@@ -9,7 +9,6 @@ Mosaic. The pure-jnp oracles live in :mod:`repro.kernels.ref`.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Dict, Tuple
 
 import jax
@@ -21,9 +20,6 @@ from repro.kernels.ssd_scan import ssd_scan_bhsd
 
 
 def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() == "cpu"
 
 

@@ -41,16 +41,27 @@ def _ssd_kernel(
         state_ref[...] = jnp.zeros_like(state_ref)
 
     xdt = xdt_ref[0, 0]                       # [Q, P]
-    da = da_ref[0, 0, 0]                      # [Q]
+    da = da_ref[0, 0]                         # [1, Q]
     b = b_ref[0, 0].astype(jnp.float32)       # [Q, N]
     c = c_ref[0, 0].astype(jnp.float32)       # [Q, N]
 
-    cum = jnp.cumsum(da)                      # [Q]
-    # Decay mask L[l, s] = exp(cum[l] - cum[s]) for l >= s.
-    diff = cum[:, None] - cum[None, :]
+    # Inclusive prefix sum of da as masked reductions over the chunk's
+    # triangles (Mosaic has no cumsum). Both layouts are needed: a column
+    # [Q, 1] (lane reduction) and a row [1, Q] (sublane reduction of da
+    # moved to a column through the diagonal).
     rows = jax.lax.broadcasted_iota(jnp.int32, (q_len, q_len), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (q_len, q_len), 1)
-    l_mat = jnp.exp(jnp.where(rows >= cols, diff, NEG_INF))
+    lower = rows >= cols
+    da_sq = jnp.broadcast_to(da, (q_len, q_len))            # [l, s] = da[s]
+    cum = jnp.sum(jnp.where(lower, da_sq, 0.0), axis=1, keepdims=True)  # [Q, 1]
+    da_col = jnp.sum(jnp.where(rows == cols, da_sq, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(
+        jnp.where(rows <= cols, jnp.broadcast_to(da_col, (q_len, q_len)), 0.0),
+        axis=0, keepdims=True,
+    )                                          # [1, Q]
+    total = jnp.sum(da, axis=1, keepdims=True)  # [1, 1]
+    # Decay mask L[l, s] = exp(cum[l] - cum[s]) for l >= s.
+    l_mat = jnp.exp(jnp.where(lower, cum - cum_row, NEG_INF))
 
     # Intra-chunk: (C Bᵀ ⊙ L) X.
     cb = jax.lax.dot_general(
@@ -63,7 +74,7 @@ def _ssd_kernel(
 
     # Inter-chunk: contribution of the carried state, decayed to each row.
     state = state_ref[...]                     # [P, N]
-    c_scaled = c * jnp.exp(cum)[:, None]       # [Q, N]
+    c_scaled = c * jnp.exp(cum)                # [Q, N]
     y_inter = jax.lax.dot_general(
         c_scaled, state, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -72,12 +83,11 @@ def _ssd_kernel(
     y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # State update: decay to chunk end, add this chunk's contribution.
-    decay_to_end = jnp.exp(cum[-1] - cum)      # [Q]
-    xd = xdt * decay_to_end[:, None]           # [Q, P]
+    xd = xdt * jnp.exp(total - cum)            # [Q, P]
     s_c = jax.lax.dot_general(
         xd, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                          # [P, N]
-    state_ref[...] = state * jnp.exp(cum[-1]) + s_c
+    state_ref[...] = state * jnp.exp(total) + s_c
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -14,24 +14,19 @@ from typing import Tuple
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer JAX; older versions default to
-    Auto axes, so omitting the kwarg is equivalent there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_debug_mesh(shape: Tuple[int, ...] = (1, 1), axes=("data", "model")):
     """Small mesh for CPU tests (requires matching host device count)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

@@ -71,7 +71,11 @@ class _SlotState:
 
 
 class Replica:
-    """One model replica with slot-batched caches."""
+    """One model replica with slot-batched caches.
+
+    Its params and slot cache live on ``device`` (the first device when
+    None), so prefill, the slot merge and decode all run there.
+    """
 
     def __init__(
         self,
@@ -83,22 +87,29 @@ class Replica:
         sets: Sequence[str] = (),
         slots: int = 4,
         max_len: int = 128,
+        device: Optional[jax.Device] = None,
     ) -> None:
         self.name = name
         self.cfg = cfg
         self.model = Model(cfg)
-        self.params = params
+        self.device = device if device is not None else jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
         self.zone = zone
         self.sets = frozenset(set(sets) | {cfg.name, "any"})
         self.slots = slots
         self.max_len = max_len
-        self.cache = self.model.init_cache(slots, max_len, enc_len=max_len)
+        self.cache = jax.device_put(
+            self.model.init_cache(slots, max_len, enc_len=max_len), self.device
+        )
         self.active: Dict[int, _SlotState] = {}   # slot index -> state
         self.alive = True
         self._decode = jax.jit(self.model.decode)
-        self._prefill_b1 = jax.jit(
-            lambda p, b, c: self.model.prefill(p, b, c)
-        )
+
+        def prefill_b1(params, batch):
+            cache = self.model.init_cache(1, max_len, enc_len=max_len)
+            return self.model.prefill(params, batch, cache)
+
+        self._prefill_b1 = jax.jit(prefill_b1)
         self.tick_times: List[float] = []
 
     # -- slot management -----------------------------------------------------------
@@ -109,23 +120,38 @@ class Replica:
                 return i
         return None
 
-    def admit(self, request: Request, placement) -> bool:
-        slot = self.free_slot()
-        if slot is None or not self.alive:
-            return False
-        prompt = jnp.asarray(request.tokens[None, :], jnp.int32)
-        small_cache = self.model.init_cache(1, self.max_len, enc_len=self.max_len)
+    def prefill(self, slot: int, tokens: np.ndarray) -> jax.Array:
+        """Prefill ``tokens`` into ``slot``; returns the last position's
+        logits ``[V]``."""
+        prompt = jax.device_put(np.asarray(tokens, np.int32)[None, :], self.device)
         batch = {"tokens": prompt}
         if self.cfg.family == "encdec":
-            batch["frames"] = jnp.zeros(
-                (1, prompt.shape[1], self.cfg.d_model), jnp.float32
+            batch["frames"] = jax.device_put(
+                np.zeros((1, prompt.shape[1], self.cfg.d_model), np.float32),
+                self.device,
             )
-        logits, filled = self._prefill_b1(self.params, batch, small_cache)
+        logits, filled = self._prefill_b1(self.params, batch)
         # Merge the single-sequence cache into this replica's slot.
         self.cache = jax.tree.map(
             lambda big, one: big.at[:, slot].set(one[:, 0]), self.cache, filled
         )
-        first_token = int(jnp.argmax(logits[0, -1]))
+        return logits[0, -1]
+
+    def decode(self, tokens: np.ndarray, positions: np.ndarray) -> jax.Array:
+        """One batched decode step over every slot; returns logits
+        ``[slots, V]``."""
+        logits, self.cache = self._decode(
+            self.params, self.cache,
+            jax.device_put(tokens, self.device),
+            jax.device_put(positions, self.device),
+        )
+        return logits[:, 0, :]
+
+    def admit(self, request: Request, placement) -> bool:
+        slot = self.free_slot()
+        if slot is None or not self.alive:
+            return False
+        first_token = int(jnp.argmax(self.prefill(slot, request.tokens)))
         self.active[slot] = _SlotState(
             request=request,
             position=len(request.tokens),
@@ -149,11 +175,9 @@ class Replica:
         for slot, st in self.active.items():
             tokens[slot] = st.last_token
             positions[slot] = st.position
-        logits, self.cache = self._decode(
-            self.params, self.cache,
-            jnp.asarray(tokens), jnp.asarray(positions),
+        next_tokens = np.asarray(
+            jnp.argmax(self.decode(tokens, positions), axis=-1)
         )
-        next_tokens = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
         finished: List[Tuple[Request, object]] = []
         for slot in list(self.active):
             st = self.active[slot]
