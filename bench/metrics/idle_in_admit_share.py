@@ -1,0 +1,15 @@
+"""Replica admission: the share of the traced window in which a device runs
+no operation while a replica on that device is admitting a request
+(``serve.admit``: prefill and merge dispatch, the first token's read-back),
+mean over the cell's devices."""
+from bench import program_spans
+
+
+def of(spans):
+    share = spans.idle_inside("serve.admit")
+    return None if share is None else 100.0 * share
+
+
+def read(d):
+    spans = program_spans.load(d)
+    return None if spans is None else of(spans)

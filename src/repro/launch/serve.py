@@ -2,7 +2,8 @@
 
 Builds a zoned edge/cloud replica deployment of one model at its published
 widths, loads a tAPP script (file or the default below), submits a
-synthetic request mix, and reports placements and latency in ticks.
+synthetic request mix, and reports placements, latency in ticks, and queue
+wait, time to first token and latency in ms from each request's own times.
 ``--reduced`` swaps in the small same-family config, for CPU runs only.
 
 The functions here are the one serving path: ``chip_smoke.py`` and the
@@ -187,6 +188,10 @@ def main() -> None:
         lat = sorted(r.finished_tick - r.submitted_tick for r in done)
         print(f"latency ticks: mean={statistics.fmean(lat):.1f} "
               f"p50={lat[len(lat) // 2]} max={lat[-1]}")
+        for label, stamp in (("wait", "admitted_s"), ("ttft", "first_token_s"),
+                             ("latency", "finished_s")):
+            ms = sorted(1e3 * (getattr(r, stamp) - r.submitted_s) for r in done)
+            print(f"{label} ms: p50={ms[len(ms) // 2]:.1f} max={ms[-1]:.1f}")
     by_tag = {}
     for r in done:
         by_tag.setdefault(r.tag or "untagged", []).append(r.replica)

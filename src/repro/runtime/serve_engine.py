@@ -11,12 +11,16 @@ The data-plane realisation of the paper's control plane:
   * **continuous batching**: prefill admits a sequence into a free slot;
     every engine tick runs ONE batched decode step per replica across all
     active slots (fixed batch shape → no recompilation);
-  * **straggler mitigation**: tick-time EMA per replica; slow replicas
+  * **straggler mitigation**: decode-step-time EMA per replica; slow replicas
     are reported to the watcher with saturated capacity so tAPP policies
     route around them until they recover (the paper's ``invalidate``
     machinery doing data-plane duty);
   * **failure handling**: a dead replica is marked unreachable; its
     queued work is rescheduled by the same policy evaluation.
+
+While a JAX profiler session runs, the engine loop, admission, each decode
+step and each device-to-host read are spans of the session's trace
+(``repro.runtime.spans``); each ``Request`` carries its own times.
 """
 from __future__ import annotations
 
@@ -42,6 +46,7 @@ from repro.core.scheduler.topology import DistributionPolicy
 from repro.core.scheduler.watcher import Watcher
 from repro.models.api import Model
 from repro.models.config import ModelConfig
+from repro.runtime.spans import span
 
 
 @dataclasses.dataclass
@@ -60,6 +65,11 @@ class Request:
     error: Optional[str] = None
     submitted_tick: int = 0
     finished_tick: int = 0
+    # ``time.perf_counter()`` seconds at each change of state.
+    submitted_s: float = dataclasses.field(default_factory=time.perf_counter)
+    admitted_s: Optional[float] = None      # admission into a slot began
+    first_token_s: Optional[float] = None   # first token read back
+    finished_s: Optional[float] = None      # last token read back
 
 
 @dataclasses.dataclass
@@ -110,7 +120,9 @@ class Replica:
             return self.model.prefill(params, batch, cache)
 
         self._prefill_b1 = jax.jit(prefill_b1)
-        self.tick_times: List[float] = []
+        # Host seconds of the last decode step, and decode steps taken.
+        self.last_step_s = 0.0
+        self.steps = 0
 
     # -- slot management -----------------------------------------------------------
 
@@ -151,16 +163,21 @@ class Replica:
         slot = self.free_slot()
         if slot is None or not self.alive:
             return False
-        first_token = int(jnp.argmax(self.prefill(slot, request.tokens)))
-        self.active[slot] = _SlotState(
-            request=request,
-            position=len(request.tokens),
-            last_token=first_token,
-            placement=placement,
-        )
-        request.state = "running"
-        request.replica = self.name
-        request.output.append(first_token)
+        request.admitted_s = time.perf_counter()
+        with span("serve.admit", request=request.request_id, device=self.device.id):
+            first = jnp.argmax(self.prefill(slot, request.tokens))
+            with span("serve.sync", kind="admit", request=request.request_id):
+                first_token = int(first)
+            request.first_token_s = time.perf_counter()
+            self.active[slot] = _SlotState(
+                request=request,
+                position=len(request.tokens),
+                last_token=first_token,
+                placement=placement,
+            )
+            request.state = "running"
+            request.replica = self.name
+            request.output.append(first_token)
         return True
 
     # -- decode tick --------------------------------------------------------------------
@@ -169,30 +186,34 @@ class Replica:
         """One batched decode step; returns finished (request, placement)."""
         if not self.active or not self.alive:
             return []
-        t0 = time.time()
-        tokens = np.zeros((self.slots,), np.int32)
-        positions = np.zeros((self.slots,), np.int32)
-        for slot, st in self.active.items():
-            tokens[slot] = st.last_token
-            positions[slot] = st.position
-        next_tokens = np.asarray(
-            jnp.argmax(self.decode(tokens, positions), axis=-1)
-        )
-        finished: List[Tuple[Request, object]] = []
-        for slot in list(self.active):
-            st = self.active[slot]
-            st.position += 1
-            st.last_token = int(next_tokens[slot])
-            st.request.output.append(st.last_token)
-            done = (
-                len(st.request.output) >= st.request.max_new_tokens
-                or st.position >= self.max_len - 1
-            )
-            if done:
-                st.request.state = "done"
-                finished.append((st.request, st.placement))
-                del self.active[slot]
-        self.tick_times.append(time.time() - t0)
+        t0 = time.perf_counter()
+        with span("serve.step", device=self.device.id):
+            tokens = np.zeros((self.slots,), np.int32)
+            positions = np.zeros((self.slots,), np.int32)
+            for slot, st in self.active.items():
+                tokens[slot] = st.last_token
+                positions[slot] = st.position
+            next_ids = jnp.argmax(self.decode(tokens, positions), axis=-1)
+            with span("serve.sync", kind="decode"):
+                next_tokens = np.asarray(next_ids)
+            now = time.perf_counter()
+            finished: List[Tuple[Request, object]] = []
+            for slot in list(self.active):
+                st = self.active[slot]
+                st.position += 1
+                st.last_token = int(next_tokens[slot])
+                st.request.output.append(st.last_token)
+                done = (
+                    len(st.request.output) >= st.request.max_new_tokens
+                    or st.position >= self.max_len - 1
+                )
+                if done:
+                    st.request.state = "done"
+                    st.request.finished_s = now
+                    finished.append((st.request, st.placement))
+                    del self.active[slot]
+        self.last_step_s = time.perf_counter() - t0
+        self.steps += 1
         return finished
 
     def fail(self) -> None:
@@ -321,16 +342,17 @@ class ServingEngine:
     # -- engine loop ----------------------------------------------------------------------
 
     def step_once(self) -> None:
-        self.tick += 1
-        self._heartbeats()
-        self._admit_queued()
-        for replica in self.replicas.values():
-            finished = replica.step()
-            for request, placement in finished:
-                request.finished_tick = self.tick
-                placement.complete()
-                self.done.append(request)
-        self._flag_stragglers()
+        with span("serve.tick"):
+            self.tick += 1
+            self._heartbeats()
+            self._admit_queued()
+            for replica in self.replicas.values():
+                finished = replica.step()
+                for request, placement in finished:
+                    request.finished_tick = self.tick
+                    placement.complete()
+                    self.done.append(request)
+            self._flag_stragglers()
 
     def run_until_done(self, max_ticks: int = 1000) -> None:
         for _ in range(max_ticks):
@@ -405,11 +427,11 @@ class ServingEngine:
 
     def _flag_stragglers(self) -> None:
         for replica in self.replicas.values():
-            # Skip the first tick: it includes jit compilation, which would
+            # Skip the first step: it includes jit compilation, which would
             # poison the EMA baseline (warmup exclusion).
-            if len(replica.tick_times) < 2:
+            if replica.steps < 2:
                 continue
-            dt = replica.tick_times[-1]
+            dt = replica.last_step_s
             ema = self._ema.get(replica.name)
             if ema is not None and dt > self.straggler_factor * ema:
                 self.stragglers_flagged += 1

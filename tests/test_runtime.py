@@ -223,7 +223,43 @@ class TestServingEngine:
         assert {r.replica for r in reqs} == {"r1", "r2"}
 
 
+class TestRequestTimes:
+    def test_each_time_is_set_when_its_state_changes(self):
+        import time as _time
+
+        engine = ServingEngine(tapp_script=None)
+        engine.add_controller("C", zone="z")
+        engine.add_replica(_small_replica("r", "z", slots=2, seed=3))
+        before = _time.perf_counter()
+        req = engine.submit("smollm-135m", [5, 6, 7], max_new_tokens=4)
+        assert before <= req.submitted_s <= _time.perf_counter()
+        assert req.admitted_s is req.first_token_s is req.finished_s is None
+        engine.step_once()
+        # Admitted and decoded once: two tokens, not yet finished.
+        assert len(req.output) == 2 and req.finished_s is None
+        assert req.submitted_s <= req.admitted_s <= req.first_token_s
+        engine.run_until_done(max_ticks=20)
+        assert req.state == "done" and req.first_token_s < req.finished_s
+
+
 class TestStragglerMitigation:
+    def test_replica_counts_decode_steps_and_times_the_last(self):
+        import time as _time
+
+        engine = ServingEngine(tapp_script=None)
+        engine.add_controller("C", zone="z")
+        replica = _small_replica("r", "z", slots=2, seed=7)
+        engine.add_replica(replica)
+        assert replica.step() == [] and replica.steps == 0
+        req = engine.submit("smollm-135m", [5, 6], max_new_tokens=3)
+        t0 = _time.perf_counter()
+        engine.run_until_done(max_ticks=20)
+        elapsed = _time.perf_counter() - t0
+        assert req.state == "done"
+        # The first token comes from admission; two decode steps follow.
+        assert replica.steps == 2
+        assert 0.0 < replica.last_step_s < elapsed
+
     def test_slow_replica_is_flagged_and_routed_around(self, monkeypatch):
         import time as _time
 
@@ -240,7 +276,7 @@ class TestStragglerMitigation:
         for _ in range(8):
             engine.submit("smollm-135m", [1, 2], max_new_tokens=3)
         engine.run_until_done(max_ticks=80)
-        assert fast.tick_times and slow.tick_times
+        assert fast.steps >= 2 and slow.steps >= 2
 
         # Make 'slow' a straggler: its decode call stalls (timed region).
         orig_decode = slow._decode
