@@ -16,11 +16,15 @@ from repro.models.config import ModelConfig
 from repro.models.layers import basic
 from repro.models.layers.attention import (
     _project_qkv,
+    _sdpa,
     attend_cached,
     attend_cross,
     attend_full,
     init_attention,
     init_kv_cache,
+    kv_cache_shape,
+    positions_last,
+    write_kv_prefix,
 )
 from repro.sharding.ctx import constrain
 
@@ -151,15 +155,17 @@ def init_cache(
     enc_len: int,
     dtype=jnp.bfloat16,
 ) -> Dict:
+    """Self- and cross-attention K/V per decoder layer, each stacked
+    ``[L, B, KV, Dh, T]`` (positions last)."""
     k, v = init_kv_cache(cfg, batch, max_len, dtype)
 
     def stack(leaf):
         return jnp.broadcast_to(leaf[None], (cfg.n_layers,) + leaf.shape).copy()
 
-    cross_shape = (batch, enc_len, cfg.n_kv_heads, cfg.head_dim)
+    cross_shape = kv_cache_shape(cfg, batch, enc_len)
     return {
-        "self_k": stack(k),
-        "self_v": stack(v),
+        "self_k": jax.tree.map(stack, k),
+        "self_v": jax.tree.map(stack, v),
         "cross_k": jnp.zeros((cfg.n_layers,) + cross_shape, dtype),
         "cross_v": jnp.zeros((cfg.n_layers,) + cross_shape, dtype),
     }
@@ -185,8 +191,8 @@ def prefill(
         x = constrain(x, ("dp", "tp", None))  # sequence-parallel carry
         h = basic.apply_norm(cfg, layer["self_norm"], x)
         q, k, v = _project_qkv(cfg, layer["self_attn"], h, positions=positions)
-        new_sk = ck.at[:, :s].set(k.astype(ck.dtype))
-        new_sv = cv.at[:, :s].set(v.astype(cv.dtype))
+        new_sk = write_kv_prefix(ck, k)
+        new_sv = write_kv_prefix(cv, v)
         h = attend_full(cfg, layer["self_attn"], h, positions, causal=True)
         x = x + h
         h = basic.apply_norm(cfg, layer["cross_norm"], x)
@@ -197,7 +203,9 @@ def prefill(
         x = x + h
         h = basic.apply_norm(cfg, layer["ffn_norm"], x)
         x = x + basic.apply_ffn(cfg, layer["ffn"], h)
-        return x, (new_sk, new_sv, xk.astype(ck.dtype), xv.astype(cv.dtype))
+        xk = positions_last(xk).astype(cache["cross_k"].dtype)
+        xv = positions_last(xv).astype(cache["cross_v"].dtype)
+        return x, (new_sk, new_sv, xk, xv)
 
     x, (sk, sv, xk, xv) = jax.lax.scan(
         body, x, (params["decoder"], cache["self_k"], cache["self_v"])
@@ -226,8 +234,6 @@ def decode_step(
         x = x + h
         h = basic.apply_norm(cfg, layer["cross_norm"], x)
         # Cross attention against the precomputed encoder K/V.
-        from repro.models.layers.attention import _sdpa
-
         q, _, _ = _project_qkv(cfg, layer["cross_attn"], h, use_rope=False)
         o = _sdpa(q, xk.astype(cdt), xv.astype(cdt), None)
         o = o.reshape(*o.shape[:-2], cfg.n_heads * cfg.head_dim)

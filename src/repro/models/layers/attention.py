@@ -1,5 +1,10 @@
 """Grouped-query attention with RoPE / qk-norm / bias variants + KV cache.
 
+The KV cache keeps positions last: K and V are ``[B, KV, Dh, T]``. That is
+the layout the decode dots read with positions minor, and the one-token
+write is a select on the position axis in that same layout, so a decode
+step reads and writes the cache as stored, with no relayout of it.
+
 Three entry points:
   * :func:`attend_full`   — full-sequence causal (train / prefill);
   * :func:`attend_cached` — one-step decode against a KV cache;
@@ -88,18 +93,24 @@ def _sdpa(
     v: jax.Array,
     mask: Optional[jax.Array],
 ) -> jax.Array:
-    """q: [B,S,H,D]; k,v: [B,T,KV,D] — grouped-query dot-product attention."""
+    """q: [B,S,H,D]; k,v: [B,KV,D,T] (positions last) — grouped-query
+    dot-product attention. mask broadcasts against [B,KV,G,S,T]."""
     b, s, h, d = q.shape
-    t, kvh = k.shape[1], k.shape[2]
+    kvh = k.shape[1]
     group = h // kvh
     qg = q.reshape(b, s, kvh, group, d)
-    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
+    scores = jnp.einsum("bskgd,bkdt->bkgst", qg, k).astype(jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(d))
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+    out = jnp.einsum("bkgst,bkdt->bskgd", probs, v)
     return out.reshape(b, s, h, d)
+
+
+def positions_last(x: jax.Array) -> jax.Array:
+    """[B,T,KV,Dh] (as projected) → [B,KV,Dh,T] (the cache layout)."""
+    return x.transpose(0, 2, 3, 1)
 
 
 def attend_full(
@@ -122,7 +133,7 @@ def attend_full(
 
         out = flash_attention(q, k, v, causal=causal)
     else:
-        out = _sdpa(q, k, v, mask)
+        out = _sdpa(q, positions_last(k), positions_last(v), mask)
     out = out.reshape(*out.shape[:-2], cfg.n_heads * cfg.head_dim)
     return out @ params["wo"].astype(cdt)
 
@@ -135,7 +146,7 @@ def attend_cached(
     cache_v: jax.Array,
     position: jax.Array,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode. x: [B,1,D]; cache_{k,v}: [B,T,KV,Dh]; position: [B].
+    """One-token decode. x: [B,1,D]; cache_{k,v}: [B,KV,Dh,T]; position: [B].
 
     Returns (attn output [B,1,D], new cache_k, new cache_v). The new token's
     K/V are written at ``position``; attention masks out cache slots beyond
@@ -146,16 +157,17 @@ def attend_cached(
         cfg, params, x, positions=position[:, None]
     )
     ref = cache_k["q"] if isinstance(cache_k, dict) else cache_k
-    b, t = ref.shape[0], ref.shape[1]
+    t_idx = jnp.arange(ref.shape[-1])[None, :]  # [1,T]
 
-    # In-place one-slot write (lowers to scatter; aliases under donation —
-    # a full-cache select here would force whole-cache copies per layer).
-    rows = jnp.arange(b)
-    cache_k = write_kv(cfg, cache_k, k_new[:, 0], rows, position)
-    cache_v = write_kv(cfg, cache_v, v_new[:, 0], rows, position)
+    # The write is a select on the position axis: it reads and writes the
+    # cache in its stored layout (a scatter there wants head_dim minor and
+    # makes the compiler relayout the layer's whole cache around it).
+    at = t_idx == position[:, None]  # [B,T]
+    cache_k = write_kv(cache_k, k_new[:, 0], at)
+    cache_v = write_kv(cache_v, v_new[:, 0], at)
 
     # Mask: only slots <= position are attendable.
-    valid = (jnp.arange(t)[None, :] <= position[:, None])  # [B,T]
+    valid = t_idx <= position[:, None]  # [B,T]
     mask = valid[:, None, None, None, :]  # [B,KV,G,1,T]
     out = _sdpa(q, dequant_kv(cache_k, cdt), dequant_kv(cache_v, cdt), mask)
     out = out.reshape(*out.shape[:-2], cfg.n_heads * cfg.head_dim)
@@ -171,31 +183,38 @@ def attend_cross(
     """Cross attention (decoder query, encoder memory); no mask, no rope."""
     cdt = _dtype(cfg.compute_dtype)
     q, k, v = _project_qkv(cfg, params, x, kv_input=enc_out, use_rope=False)
-    out = _sdpa(q, k, v, None)
+    out = _sdpa(q, positions_last(k), positions_last(v), None)
     out = out.reshape(*out.shape[:-2], cfg.n_heads * cfg.head_dim)
     return out @ params["wo"].astype(cdt)
 
 
+def kv_cache_shape(cfg, batch: int, length: int) -> Tuple[int, ...]:
+    """Shape of one K or V cache: ``[B, KV, Dh, T]``, positions last."""
+    return (batch, cfg.n_kv_heads, cfg.head_dim, length)
+
+
 def init_kv_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
-    """KV cache pair. With ``cfg.kv_cache_dtype == "int8"`` each of K/V is
-    a dict {"q": int8 [B,T,KV,D], "scale": f32 [B,T,KV,1]} (per-token,
-    per-head absmax quantisation) — halving decode's dominant HBM term."""
-    shape = (batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    """KV cache pair, each :func:`kv_cache_shape`. With
+    ``cfg.kv_cache_dtype == "int8"`` each of K/V is a dict {"q": int8
+    [B,KV,Dh,T], "scale": f32 [B,KV,1,T]} (per-token, per-head absmax
+    quantisation) — halving decode's dominant HBM term."""
+    shape = kv_cache_shape(cfg, batch, max_len)
     if cfg.kv_cache_dtype == "int8":
         def q8():
             return {
                 "q": jnp.zeros(shape, jnp.int8),
-                "scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
+                "scale": jnp.zeros(shape[:-2] + (1, max_len), jnp.float32),
             }
         return q8(), q8()
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
 def quant_kv(x: jax.Array):
-    """Per-(token, head) absmax int8 quantisation of K or V rows."""
+    """Per-(token, head) absmax int8 quantisation of positions-last K or V
+    (``[..., Dh, T]``: the absmax runs over head_dim, axis -2)."""
     xf = x.astype(jnp.float32)
     scale = jnp.maximum(
-        jnp.max(jnp.abs(xf), axis=-1, keepdims=True) / 127.0, 1e-20
+        jnp.max(jnp.abs(xf), axis=-2, keepdims=True) / 127.0, 1e-20
     )
     q = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
     return {"q": q, "scale": scale}
@@ -207,23 +226,29 @@ def dequant_kv(c, dtype) -> jax.Array:
     return c.astype(dtype)
 
 
-def write_kv(cfg, cache, new: jax.Array, rows, position):
-    """Write one token's K or V into the cache at [rows, position]."""
+def write_kv(cache, new: jax.Array, at: jax.Array):
+    """Write one token's K or V (``new`` [B,KV,Dh]) into the positions-last
+    cache where ``at`` ([B,T] bool, one true per row) is set."""
+    at = at[:, None, None, :]
+    new = new[..., None]  # [B,KV,Dh,1]
     if isinstance(cache, dict):
         enc = quant_kv(new)
         return {
-            "q": cache["q"].at[rows, position].set(enc["q"]),
-            "scale": cache["scale"].at[rows, position].set(enc["scale"]),
+            "q": jnp.where(at, enc["q"], cache["q"]),
+            "scale": jnp.where(at, enc["scale"], cache["scale"]),
         }
-    return cache.at[rows, position].set(new.astype(cache.dtype))
+    return jnp.where(at, new.astype(cache.dtype), cache)
 
 
-def write_kv_prefix(cfg, cache, new: jax.Array, length: int):
-    """Write the first ``length`` positions (prefill path)."""
+def write_kv_prefix(cache, new: jax.Array):
+    """Write a prompt's K or V (``new`` [B,S,KV,Dh], as projected) into the
+    first S positions of the positions-last cache (prefill path)."""
+    new = positions_last(new)
+    s = new.shape[-1]
     if isinstance(cache, dict):
         enc = quant_kv(new)
         return {
-            "q": cache["q"].at[:, :length].set(enc["q"]),
-            "scale": cache["scale"].at[:, :length].set(enc["scale"]),
+            "q": cache["q"].at[..., :s].set(enc["q"]),
+            "scale": cache["scale"].at[..., :s].set(enc["scale"]),
         }
-    return cache.at[:, :length].set(new.astype(cache.dtype))
+    return cache.at[..., :s].set(new.astype(cache.dtype))

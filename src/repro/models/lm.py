@@ -19,10 +19,12 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.models.layers import basic
 from repro.models.layers.attention import (
+    _project_qkv,
     attend_cached,
     attend_full,
     init_attention,
     init_kv_cache,
+    write_kv_prefix,
 )
 from repro.models.layers.moe import apply_moe, init_moe
 from repro.models.layers.ssm import (
@@ -235,15 +237,11 @@ def prefill(
             c = period_cache[f"pos{i}"]
             h = basic.apply_norm(cfg, sub["mixer_norm"], x)
             if mixer == "attn":
-                from repro.models.layers.attention import _project_qkv
-
-                q, k, v = _project_qkv(cfg, sub["attn"], h, positions=positions)
-                # Write the prompt K/V into the cache prefix.
-                from repro.models.layers.attention import write_kv_prefix
-
-                ck = write_kv_prefix(cfg, c["k"], k, s)
-                cv = write_kv_prefix(cfg, c["v"], v, s)
-                new_cache[f"pos{i}"] = {"k": ck, "v": cv}
+                _, k, v = _project_qkv(cfg, sub["attn"], h, positions=positions)
+                new_cache[f"pos{i}"] = {
+                    "k": write_kv_prefix(c["k"], k),
+                    "v": write_kv_prefix(c["v"], v),
+                }
                 h = attend_full(cfg, sub["attn"], h, positions)
             else:
                 h, mamba_state = apply_mamba_with_state(cfg, sub["mamba"], h)
@@ -272,7 +270,12 @@ def decode_step(
     token: jax.Array,       # [B] int32 — the most recent token
     position: jax.Array,    # [B] int32 — its cache slot
 ) -> Tuple[jax.Array, Dict]:
-    """One incremental decode step. Returns (logits [B,1,V], new cache)."""
+    """One incremental decode step. Returns (logits [B,1,V], new cache).
+
+    Each period's cache comes in as the scan's ``xs`` and leaves as its
+    ``ys``: the token write is a select in the stored layout, written
+    straight into the new stacked cache, so the step copies no cache.
+    """
     x = basic.embed(cfg, params["embed"], token[:, None])
 
     def scan_body(x, inputs):
@@ -341,11 +344,3 @@ def apply_mamba_with_state(cfg, params: Dict, x: jax.Array):
     cache = {"conv": conv_window, "ssm": final_state}
     return out, cache
 
-
-def _cache_len(cfg: ModelConfig, cache: Dict) -> int:
-    for i, (mixer, _) in enumerate(cfg.layer_pattern()):
-        if mixer == "attn":
-            k = cache[f"pos{i}"]["k"]
-            ref = k["q"] if isinstance(k, dict) else k
-            return ref.shape[2]
-    return 0

@@ -15,8 +15,9 @@ Default placement (hillclimbed variants live in perf configs):
   * MoE expert stacks [E, ...]: expert-parallel on the TP axis when E
     divides it, otherwise tensor-parallel within experts.
   * Embeddings [V, d]: vocab-parallel (falls back to d).
-  * Batches: [B, ...] over (pod, data); KV caches shard T on the TP axis
-    for decode (B already covers the data axes), SSM states shard heads.
+  * Batches: [B, ...] over (pod, data); KV caches ([L, B, KV, Dh, T],
+    positions last) shard T on the TP axis for decode (B already covers
+    the data axes), SSM states shard heads.
 """
 from __future__ import annotations
 
@@ -213,9 +214,10 @@ def cache_shardings(
     mesh: Mesh,
     cache_shapes: Any,
 ) -> Any:
-    """KV caches: [L, B, T, KV, Dh] — B over dp, T over TP (flash-decode
-    style sequence sharding; GSPMD handles the softmax reduction). SSM
-    states: [L, B, H, P, N] — H over TP. Conv caches: channel over TP."""
+    """KV caches: [L, B, KV, Dh, T] (positions last) — B over dp, T over TP
+    (flash-decode style sequence sharding; GSPMD handles the softmax
+    reduction). SSM states: [L, B, H, P, N] — H over TP. Conv caches:
+    channel over TP."""
     dp = policy.dp_axes
     tp = policy.tp_axis
 
@@ -224,13 +226,11 @@ def cache_shardings(
         leafname = names[-1]
         shape = tuple(leaf.shape)
         kv_names = ("k", "v", "self_k", "self_v", "cross_k", "cross_v")
-        if leafname in ("q", "scale") and len(names) >= 2 and names[-2] in kv_names:
-            # int8 KV cache: q mirrors the KV layout; scale drops head_dim.
+        is_int8 = len(names) >= 2 and names[-2] in kv_names
+        if leafname in kv_names or (leafname in ("q", "scale") and is_int8):
+            # int8 KV cache: q mirrors the KV layout; scale has head_dim 1.
             seq = tp if policy.shard_kv_seq else None
-            spec = P(None, dp, seq, None, None)
-        elif leafname in kv_names:
-            seq = tp if policy.shard_kv_seq else None
-            spec = P(None, dp, seq, None, None)
+            spec = P(None, dp, None, None, seq)
         elif leafname == "ssm":      # [L, B, H, P, N]
             spec = P(None, dp, tp, None, None)
         elif leafname == "conv":     # [L, B, W-1, C]

@@ -201,3 +201,64 @@ class TestSSD:
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(st_full), np.asarray(st2),
                                    rtol=1e-4, atol=1e-4)
+
+
+# One family each: plain GQA decoder, attention + mamba + MoE hybrid,
+# encoder-decoder, and the int8-quantised KV cache.
+_MIXED_POSITION_CASES = {
+    "lm": ("smollm_135m", {}),
+    "hybrid": ("jamba_1_5_large_398b", {}),
+    "encdec": ("whisper_small", {}),
+    "int8_kv": ("smollm_135m", {"kv_cache_dtype": "int8"}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MIXED_POSITION_CASES))
+def test_decode_slots_at_mixed_positions_match_forward(case):
+    """One batched decode step over slots at positions 0, mid and
+    ``max_len - 2``, each filled the way a replica admits a request
+    (batch-1 prefill merged into its slot), gives each slot the logits of
+    a full forward pass over that slot's tokens."""
+    arch, extra = _MIXED_POSITION_CASES[case]
+    cfg = dataclasses.replace(
+        smoke_config(arch), compute_dtype="float32",
+        moe_capacity_factor=16.0, **extra,
+    )
+    model = Model(cfg)
+    params = model.init_params(RNG)
+    max_len, enc_len = 16, 8
+    positions = [0, max_len // 2 - 1, max_len - 2]
+    keys = jax.random.split(jax.random.PRNGKey(1), 2 * len(positions))
+    cache = model.init_cache(len(positions), max_len, enc_len=enc_len)
+    expected, last = [], []
+    for slot, pos in enumerate(positions):
+        toks = jax.random.randint(keys[2 * slot], (1, pos + 1), 0, cfg.vocab_size)
+        prompt = {"tokens": toks[:, :pos]}
+        if cfg.family == "encdec":
+            frames = jax.random.normal(
+                keys[2 * slot + 1], (1, enc_len, cfg.d_model), jnp.float32
+            )
+            enc = ed_mod.encode(cfg, params, frames)
+            expected.append(ed_mod.decode_full(cfg, params, toks, enc)[0, -1])
+            # The cross K/V come with a prompt; at position 0 its one token
+            # is written again by the decode step.
+            prompt = {"frames": frames, "tokens": toks[:, : max(pos, 1)]}
+        else:
+            expected.append(lm_mod.forward(cfg, params, toks)[0][0, -1])
+        if prompt["tokens"].shape[1]:
+            one = model.init_cache(1, max_len, enc_len=enc_len)
+            _, one = model.prefill(params, prompt, one)
+            cache = jax.tree.map(
+                lambda big, o, s=slot: big.at[:, s].set(o[:, 0]), cache, one
+            )
+        last.append(toks[0, pos])
+    step, new_cache = model.decode(
+        params, cache, jnp.stack(last), jnp.asarray(positions, jnp.int32)
+    )
+    assert jax.tree.map(jnp.shape, new_cache) == jax.tree.map(jnp.shape, cache)
+    # int8 K/V round each value to 1/127 of its row's absmax.
+    tol = 2e-2 if cfg.kv_cache_dtype == "int8" else 1e-4
+    for slot, want in enumerate(expected):
+        err = float(jnp.max(jnp.abs(step[slot, 0] - want)))
+        scale = float(jnp.max(jnp.abs(want))) + 1e-9
+        assert err / scale < tol, (case, positions[slot], err, scale)

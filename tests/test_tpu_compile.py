@@ -1,16 +1,20 @@
-"""The Pallas kernels compile for a TPU v5e chip at real widths.
+"""Programs compile for a TPU v5e chip at real widths.
 
-Nothing runs: each test lowers one kernel with ``interpret=False`` for a
-described (not attached) ``v5e:2x2`` topology and checks that the compiled
-program holds the Mosaic kernel (``tpu_custom_call``). This catches what
+Nothing runs: each test lowers a program for a described (not attached)
+``v5e:2x2`` topology. The Pallas kernels, lowered with ``interpret=False``,
+must hold the Mosaic kernel (``tpu_custom_call``). This catches what
 interpret mode cannot, such as primitives the TPU lowering lacks, tiles
-out of alignment, and more VMEM than a kernel may use.
+out of alignment, and more VMEM than a kernel may use. The served decode
+step must read and write its K/V cache as stored: the compiled program may
+hold no buffer of one layer's cache (a relayout copy or a slice of it).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +26,7 @@ from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.moe_gmm import gmm
 from repro.kernels.ssd_scan import ssd_scan_bhsd
+from repro.models.api import Model
 
 
 @pytest.fixture(scope="module")
@@ -98,3 +103,63 @@ def test_ssd_scan_mamba2(one_chip):
         interpret=False,
     ).compile()
     _assert_kernel(compiled)
+
+
+# Ops that name a buffer without writing one.
+_NO_BUFFER = ("bitcast", "get-tuple-element", "parameter", "tuple")
+
+
+def _buffers(hlo_text):
+    """(name, op, element count) of every instruction outside fused
+    computations: the buffers the program writes."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", hlo_text))
+    comp, out = None, []
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) .*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+        elif line.startswith("}"):
+            comp = None
+        elif comp is not None and comp not in fused:
+            inst = re.match(
+                r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                line,
+            )
+            if inst and inst.group(3) not in _NO_BUFFER:
+                dims = [int(d) for d in inst.group(2).split(",") if d]
+                out.append((inst.group(1), inst.group(3), math.prod(dims)))
+    return out
+
+
+# The served replica (smollm-135m, 64 slots x 2048 positions), a wider GQA
+# decoder, and the encoder-decoder whose self K/V take the same path. Only
+# whisper's cross K/V, which decode hands back as they came in, are copied
+# whole into the (undonated) output.
+@pytest.mark.parametrize(
+    "arch,slots,max_len,whole_copies",
+    [("smollm_135m", 64, 2048, 0), ("qwen1_5_0_5b", 16, 2048, 0),
+     ("whisper_small", 16, 448, 2)],
+)
+def test_decode_keeps_kv_cache_as_stored(
+    one_chip, arch, slots, max_len, whole_copies
+):
+    model = Model(get_config(arch))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: _spec(one_chip, s.shape, s.dtype), tree
+        )
+
+    params = on_chip(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_cache(slots, max_len, enc_len=max_len)
+    ))
+    step = _spec(one_chip, (slots,), jnp.int32)
+    compiled = jax.jit(model.decode).lower(params, cache, step, step).compile()
+    buffers = _buffers(compiled.as_text())
+    leaves = jax.tree.leaves(cache)
+    one_layer = {math.prod(leaf.shape[1:]) for leaf in leaves}
+    whole = {math.prod(leaf.shape) for leaf in leaves}
+    assert [b for b in buffers if b[2] in one_layer] == []
+    copies = [b for b in buffers if b[1] == "copy" and b[2] in whole]
+    assert len(copies) == whole_copies, copies
