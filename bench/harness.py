@@ -30,7 +30,8 @@ import numpy as np
 
 from bench import counts, traffic
 from bench import trace as tr
-from bench.weights import make_weights, program_tree
+from bench.families import load_family
+from bench.reference import load_reference
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -122,20 +123,11 @@ class CompileLog:
 # ---------------------------------------------------------------------------
 
 
-def program_config(c: Dict):
-    """The program's ``ModelConfig`` with every size, dtype and constant
-    taken from the configuration file."""
-    from repro.configs import get_config
-
-    s = counts.sizes(c)
-    return dataclasses.replace(
-        get_config(c["program_arch"]),
-        n_layers=s["layers"], d_model=s["d"], n_heads=s["heads"],
-        n_kv_heads=s["kv"], head_dim=s["hd"], d_ff=s["ff"],
-        vocab_size=s["vocab"], qkv_bias=s["bias"], tie_embeddings=s["tied"],
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        param_dtype=c["param_dtype"], compute_dtype=c["compute_dtype"],
-    )
+def family(c: Dict):
+    """The module of the configuration's architecture family
+    (``bench/families/<reference>.py``): its weights, the program's layout
+    and config, and its work counts."""
+    return load_family(c["reference"])
 
 
 def replicas_per_zone(chips: int) -> int:
@@ -149,8 +141,9 @@ def build(c: Dict, seed: int, devices: Sequence):
     from repro.launch.serve import build_engine
     from repro.models import Model
 
-    cfg = program_config(c)
-    params = program_tree(make_weights(c, seed))
+    fam = family(c)
+    cfg = fam.program_config(c)
+    params = fam.program_tree(fam.make_weights(c, seed))
     want = jax.tree.structure(jax.eval_shape(Model(cfg).init_params,
                                              jax.random.PRNGKey(0)))
     if jax.tree.structure(params) != want:
@@ -251,12 +244,13 @@ class Spans:
 
     def _install_replica(self, replica) -> None:
         device = replica.device.id
+        fam = family(self.c)
 
         def admitted(ok, request, placement):
             if ok and self.recording:
                 self.admissions += 1
             if ok and self.tracing:
-                work = counts.prefill_work(self.c, len(request.tokens))
+                work = fam.prefill_work(self.c, len(request.tokens))
                 self.traced.append(Call("prefill", device, work))
 
         replica.admit = self.timed("bench.admit", replica.admit, after=admitted)
@@ -271,7 +265,7 @@ class Spans:
             positions = [st.position for st in replica.active.values()]
             out = timed_step()
             if self.tracing:
-                work = counts.decode_work(self.c, positions)
+                work = fam.decode_work(self.c, positions)
                 self.traced.append(Call("decode", device, work))
             return out
 
@@ -526,16 +520,22 @@ def check_tokens(records: List[Record]) -> int:
 
 
 def sample(records: List[Record], k: int, seed: int) -> List[Served]:
-    """``k`` finished requests drawn from the seed, with the longest."""
+    """``k`` finished requests drawn from the seed: the longest, then one
+    from each replica none of those came from, then the rest at random, so
+    that every replica's device is compared where ``k`` allows."""
     done = [r for r in records if r.request.state == "done" and r.request.output]
     if not done:
         return []
     lengths = [r.spec.prompt_len + len(r.request.output) for r in done]
     longest = int(np.argmax(lengths))
-    rest = [i for i in range(len(done)) if i != longest]
     rng = np.random.default_rng([seed, 0xC4EC])
-    picks = [longest] + list(rng.choice(rest, size=min(k - 1, len(rest)),
-                                        replace=False))
+    rest = [int(i) for i in rng.permutation(len(done)) if i != longest]
+    first, seen = [longest], {done[longest].request.replica}
+    for i in rest:
+        if done[i].request.replica not in seen:
+            first.append(i)
+            seen.add(done[i].request.replica)
+    picks = (first + [i for i in rest if i not in first])[:k]
     return [Served(np.asarray(done[i].spec.tokens), list(done[i].request.output))
             for i in picks]
 
@@ -548,11 +548,10 @@ def logit_gaps(c: Dict, seed: int, served: List[Served], *, pad_to: int,
     first."""
     import jax.numpy as jnp
 
-    from bench.reference import load_reference
-
+    fam = family(c)
     ref = load_reference(c["reference"])
-    program = ref.gap_program(tuple(sorted(model_keys(c).items())), control)
-    w = make_weights(c, seed)
+    program = ref.gap_program(tuple(sorted(fam.model_keys(c).items())), control)
+    w = fam.make_weights(c, seed)
     worst, worst_control = 0.0, (0.0 if control else None)
     for item in served:
         n, m = len(item.prompt), len(item.output)
@@ -571,16 +570,6 @@ def logit_gaps(c: Dict, seed: int, served: List[Served], *, pad_to: int,
             gaps = np.asarray(out)[:m]
         worst = max(worst, float(gaps.max()))
     return worst, worst_control
-
-
-MODEL_KEYS = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-              "num_key_value_heads", "head_dim", "intermediate_size", "vocab_size",
-              "rms_norm_eps", "rope_theta", "tie_word_embeddings", "attention_bias",
-              "qkv_bias")
-
-
-def model_keys(c: Dict) -> Dict:
-    return {k: c[k] for k in MODEL_KEYS if k in c}
 
 
 # ---------------------------------------------------------------------------
@@ -677,6 +666,10 @@ def _run_cell(c, mix, *, seed, seconds, trace, devices, end_to_end, per_layer,
             leaves = sorted(((k, v) for k, v in summary.ops.items()
                              if not k.startswith("%while")), key=lambda kv: -kv[1][0])
             log(f"[trace] top ops (s, runs) {json.dumps(leaves[:15])}")
+            stacks = sorted(summary.scopes.items(), key=lambda kv: -kv[1])
+            log(f"[trace] name stacks: {len(stacks)}, device s {sum(summary.scopes.values())} "
+                f"(ops {sum(v[0] for v in summary.ops.values())}); top "
+                f"{json.dumps(stacks[:15])}")
             programs = sorted(((k, sum(v)) for k, v in summary.modules.items()),
                               key=lambda kv: -kv[1])[:10]
             gaps = sorted(summary.idle_by_host.items(), key=lambda kv: -kv[1])[:10]

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.counts import sizes
+from bench.families.llama import sizes
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FP8_MAX = 448.0

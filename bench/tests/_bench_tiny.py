@@ -30,8 +30,9 @@ def tiny_mix(name: str = "azure-conv") -> dict:
 
 
 def run(seed: int = 2**33 + 1, *, c=None, mix=None, trace=False, control=False,
-        seconds=1.5):
+        seconds=1.5, devices=None):
     return harness.run_cell(c or tiny_config(), mix or tiny_mix(), seed=seed,
-                            seconds=seconds, trace=trace, devices=jax.devices()[:1],
+                            seconds=seconds, trace=trace,
+                            devices=devices or jax.devices()[:1],
                             per_layer=PER_LAYER, control=control, peak=CPU_PEAK,
                             log=lambda line: None)

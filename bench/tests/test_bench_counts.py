@@ -1,9 +1,10 @@
-"""The work counts and the peaks table: smollm's FLOPs and bytes equal a
-hand reckoning, decode bytes follow live length and not the cache size, and
-an unknown device is an error."""
+"""The Llama family's work counts and the peaks table: smollm's FLOPs and
+bytes equal a hand reckoning, decode bytes follow live length and not the
+cache size, and an unknown device is an error."""
 import pytest
 
 from bench import counts
+from bench.families import llama
 from bench.harness import load_config
 
 SMOLLM = load_config("smollm-135m")
@@ -14,15 +15,15 @@ def test_smollm_parameters_by_hand():
     # Q 576x576, K and V 576x192 each, O 576x576, SwiGLU 3 x 576x1536.
     per_layer = 576 * 576 + 2 * 576 * 192 + 576 * 576 + 3 * 576 * 1536
     assert per_layer == 3_538_944
-    assert counts.layer_matmul_params(SMOLLM) == per_layer
+    assert llama.layer_matmul_params(SMOLLM) == per_layer
     # Plus two norms a layer, the final norm and the tied 49152 x 576 table.
-    assert counts.weight_count(SMOLLM) == 30 * (per_layer + 2 * 576) + 576 + 49152 * 576
-    assert counts.weight_count(SMOLLM) == 134_515_008
+    assert llama.weight_count(SMOLLM) == 30 * (per_layer + 2 * 576) + 576 + 49152 * 576
+    assert llama.weight_count(SMOLLM) == 134_515_008
 
 
 def test_kv_bytes_per_token():
-    assert counts.kv_bytes_per_token(SMOLLM) == 30 * 2 * 3 * 64 * 2 == 23_040
-    assert counts.kv_bytes_per_token(QWEN) == 24 * 2 * 16 * 64 * 2 == 98_304
+    assert llama.kv_bytes_per_token(SMOLLM) == 30 * 2 * 3 * 64 * 2 == 23_040
+    assert llama.kv_bytes_per_token(QWEN) == 24 * 2 * 16 * 64 * 2 == 98_304
 
 
 def test_smollm_prefill_by_hand():
@@ -31,7 +32,7 @@ def test_smollm_prefill_by_hand():
     attention = 2 * 9 * 64 * n * (n + 1)          # causal QK^T and PV
     flops = 30 * (matmul + attention) + 2 * 576 * 49152
     nbytes = 2 * 134_515_008 + n * 23_040 + 4 * 49152
-    assert counts.prefill_work(SMOLLM, n) == (flops, nbytes)
+    assert llama.prefill_work(SMOLLM, n) == (flops, nbytes)
 
 
 def test_smollm_decode_by_hand():
@@ -39,21 +40,21 @@ def test_smollm_decode_by_hand():
     flops = 2 * (30 * 2 * 3_538_944 + 2 * 576 * 49152)
     flops += 30 * 4 * 9 * 64 * (101 + 301)
     nbytes = 2 * 134_515_008 + (100 + 300) * 23_040 + 2 * 23_040 + 4 * 2 * 49152
-    assert counts.decode_work(SMOLLM, positions) == (flops, nbytes)
+    assert llama.decode_work(SMOLLM, positions) == (flops, nbytes)
 
 
 def test_decode_bytes_follow_live_length_not_cache_size():
-    short = counts.decode_work(SMOLLM, [100])[1]
-    longer = counts.decode_work(SMOLLM, [200])[1]
+    short = llama.decode_work(SMOLLM, [100])[1]
+    longer = llama.decode_work(SMOLLM, [200])[1]
     assert longer - short == 100 * 23_040
-    assert counts.decode_work(SMOLLM, []) == (0.0, 0.0)
+    assert llama.decode_work(SMOLLM, []) == (0.0, 0.0)
     # Idle slots add nothing: one active slot of 64 costs one slot's K/V.
-    assert counts.decode_work(SMOLLM, [5])[1] < counts.decode_work(SMOLLM, [5, 5])[1]
+    assert llama.decode_work(SMOLLM, [5])[1] < llama.decode_work(SMOLLM, [5, 5])[1]
 
 
 def test_qwen_counts_its_bias():
     no_bias = dict(QWEN, qkv_bias=False)
-    assert counts.weight_count(QWEN) - counts.weight_count(no_bias) == 24 * 3 * 1024
+    assert llama.weight_count(QWEN) - llama.weight_count(no_bias) == 24 * 3 * 1024
 
 
 def test_peaks_table():

@@ -1,6 +1,7 @@
 """A run with the timed path broken underneath comes out not correct, once
 for each fault a served cell can have. (The cells have no exchange between
 chips: each replica holds a whole model on its own device.)"""
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -23,11 +24,13 @@ def first_token_altered(monkeypatch):
 
 
 def state_unchanged(monkeypatch):
-    """Decode returns its logits but leaves the cache as it was."""
+    """Decode returns its logits but leaves the cache as it was: a copy
+    taken before the call, since a decode that donates its cache deletes
+    the buffers it was given."""
     sound = serve_engine.Replica.decode
 
     def decode(self, tokens, positions):
-        before = self.cache
+        before = jax.tree.map(jnp.copy, self.cache)
         out = sound(self, tokens, positions)
         self.cache = before
         return out
@@ -45,8 +48,22 @@ def half_batch_left_out(monkeypatch):
     monkeypatch.setattr(serve_engine.Replica, "decode", decode)
 
 
+def state_unchanged_donated(monkeypatch):
+    """``state_unchanged`` under a decode that donates its cache, as the
+    served decode may: the fault still comes out not correct, not as a
+    read of a deleted buffer."""
+    sound_init = serve_engine.Replica.__init__
+
+    def init(self, *args, **kwargs):
+        sound_init(self, *args, **kwargs)
+        self._decode = jax.jit(self.model.decode, donate_argnums=(1,))
+    monkeypatch.setattr(serve_engine.Replica, "__init__", init)
+    state_unchanged(monkeypatch)
+
+
 @pytest.mark.parametrize("fault", [token_altered, first_token_altered,
-                                   state_unchanged, half_batch_left_out])
+                                   state_unchanged, state_unchanged_donated,
+                                   half_batch_left_out])
 def test_fault_makes_the_run_not_correct(monkeypatch, fault):
     fault(monkeypatch)
     # The closed loop keeps every slot busy, so each fault reaches the

@@ -111,3 +111,66 @@ def test_an_entry_of_one_cell_reads_through_its_base_reader():
                      trace=summary)
     assert load_reader("prefill_roofline.code")(data) == \
         load_reader("prefill_roofline")(data) == pytest.approx(50.0)
+
+
+def test_scopes_sum_op_time_by_name_stack():
+    """Op events named by their name stack: each stack's device seconds
+    summed over devices and clipped to the window; an op without one counts
+    under ``""``. Busy time, ops and modules read as they do without the
+    stacks."""
+    attn, ffn = "jit(decode)/while/body/attn/dot_general", "jit(decode)/while/body/ffn/mul"
+    scan = "jit(prefill_b1)/ssd_scan/exp"
+    raw = {0: [("%fusion.1 = f32[8]", 0, 4, attn), ("%fusion.2 = f32[8]", 4, 6, ffn),
+               ("%copy.3 = f32[8]", 6, 7, "")],
+           1: [("%fusion.1 = f32[8]", 1, 3, attn), ("%fusion.9 = f32[8]", 8, 14, scan)]}
+    devices = {dev: {tr.OPS_LINE: [(n, a * MS, b * MS) for n, a, b, _ in events],
+                     tr.SCOPES_LINE: [(k, a * MS, b * MS) for _, a, b, k in events],
+                     tr.MODULES_LINE: [("jit_decode(3)", 0, 7 * MS)]}
+               for dev, events in raw.items()}
+    host = [("bench.trace_window", 0, 10 * MS)]
+    s = tr.summarize_events(devices, host, tr.window_of(host, "bench.trace_window"))
+    assert s.scopes == {attn: pytest.approx(0.006), ffn: pytest.approx(0.002),
+                        "": pytest.approx(0.001), scan: pytest.approx(0.002)}
+    assert sum(s.scopes.values()) == pytest.approx(sum(v[0] for v in s.ops.values()))
+    plain = tr.summarize_events({d: {k: v for k, v in ls.items() if k != tr.SCOPES_LINE}
+                                 for d, ls in devices.items()}, host, (0, 10 * MS))
+    assert (plain.ops, plain.modules, plain.busy_s) == (s.ops, s.modules, s.busy_s)
+    assert plain.scopes == {"": pytest.approx(sum(s.scopes.values()))}
+
+
+def test_name_stacks_come_from_the_event_metadata(tmp_path):
+    """A hand-built trace file: the stack stat on an op's metadata, as a
+    string or as a reference to a stat metadata's name; an op without it,
+    and a plane not asked for, read as nothing."""
+    from bench import xplane
+
+    space = xplane._space_class()()
+    for plane_name in ("/device:TPU:0", "/device:TPU:5"):
+        plane = space.planes.add(name=plane_name)
+        plane.stat_metadata[1].name = tr.NAME_STACK_STAT
+        plane.stat_metadata[2].name = "flops"
+        plane.stat_metadata[3].name = "jit(decode)/while/body/ffn/mul"
+        plane.event_metadata[10].stats.add(metadata_id=1, str_value="jit(decode)/attn/dot")
+        plane.event_metadata[10].stats.add(metadata_id=2, str_value="64")
+        plane.event_metadata[11].stats.add(metadata_id=1, ref_value=3)
+        plane.event_metadata[12].stats.add(metadata_id=2, str_value="8")
+        line = plane.lines.add(name=tr.OPS_LINE)
+        for key in (10, 11, 12, 10):
+            line.events.add(metadata_id=key)
+        plane.lines.add(name=tr.MODULES_LINE).events.add(metadata_id=10)
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space.SerializeToString())
+    got = xplane.op_stacks(str(path), tr.OPS_LINE, tr.NAME_STACK_STAT,
+                           lambda name: name == "/device:TPU:0")
+    assert got == {"/device:TPU:0": ["jit(decode)/attn/dot", "jit(decode)/while/body/ffn/mul",
+                                     "", "jit(decode)/attn/dot"]}
+
+
+def test_events_without_stacks_count_under_the_empty_stack():
+    """The events of the two-device summary above carry no stack: their
+    time is all under ``""`` and equals the ops' time."""
+    devices = {0: {tr.OPS_LINE: [("fusion.1", 0, 5 * MS)]},
+               1: {tr.OPS_LINE: [("fusion.1", 2 * MS, 4 * MS), ("copy.2", 20 * MS, 30 * MS)]}}
+    s = tr.summarize_events(devices, [], (0, 10 * MS))
+    assert s.scopes == {"": pytest.approx(0.007)}
+    assert s.ops["fusion.1"] == (pytest.approx(0.007), 2)

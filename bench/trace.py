@@ -1,12 +1,15 @@
-"""Reduction of a profiler trace to device busy time, module time and idle
-gaps, on the trace's own clock.
+"""Reduction of a profiler trace to device busy time, module time, time per
+name stack and idle gaps, on the trace's own clock.
 
 The pure functions take plain ``(name, start_ns, end_ns)`` tuples, so they
-can be checked on hand-built event lists; ``summarize`` reads an
+can be checked on hand-built event lists; ``read_xplane`` reads an
 ``.xplane.pb`` through ``jax.profiler.ProfileData``. A TPU device plane is
 ``/device:TPU:<n>``; its ``XLA Ops`` line holds one event per operation and
-its ``XLA Modules`` line one per program run (``jit_<name>(<id>)``). Host
-spans are the ``TraceAnnotation`` events on ``/host:CPU``.
+its ``XLA Modules`` line one per program run (``jit_<name>(<id>)``). Each
+op event's metadata carries the HLO op's ``op_name`` metadata, the JAX
+name stack (``jax.named_scope`` paths among it), in its
+``NAME_STACK_STAT`` stat (read by ``bench/xplane.py``). Host spans are the
+``TraceAnnotation`` events on ``/host:CPU``.
 """
 from __future__ import annotations
 
@@ -16,11 +19,18 @@ import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+from bench import xplane
+
 Event = Tuple[str, float, float]          # (name, start_ns, end_ns)
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+#: The op events of ``OPS_LINE`` named by their name stack instead of their
+#: HLO text (not a line of the trace: ``read_xplane`` builds it).
+SCOPES_LINE = "XLA Ops by name stack"
+#: The stat of an op event's metadata that holds the HLO op's ``op_name``.
+NAME_STACK_STAT = "tf_op"
 HOST_PLANE = "/host:CPU"
 #: Host activity outside every annotated span.
 UNLABELLED = "host (no span)"
@@ -141,6 +151,8 @@ class Summary:
     modules: Dict[str, List[float]]               # program -> seconds of each run
     ops: Dict[str, Tuple[float, int]]             # op name -> device s, runs
     idle_by_host: Dict[str, float]                # host span -> idle s / device
+    #: name stack -> device s, summed over devices like ``ops``
+    scopes: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def idle_share(self) -> Optional[float]:
         if not self.busy_s or self.window_s <= 0:
@@ -160,9 +172,13 @@ def summarize_events(
     ``window`` (ns on the trace's clock)."""
     lo, hi = window
     spans = [e for e in host if e[0].startswith(host_prefix)]
-    busy, modules, ops, idle = {}, {}, {}, {}
+    busy, modules, ops, idle, scopes = {}, {}, {}, {}, {}
     for dev, lines in sorted(devices.items()):
-        op_events = clip(lines.get(OPS_LINE) or lines.get(MODULES_LINE, []), lo, hi)
+        source = lines.get(OPS_LINE) or lines.get(MODULES_LINE, [])
+        op_events = clip(source, lo, hi)
+        named = lines.get(SCOPES_LINE) or [("", start, end) for _, start, end in source]
+        for stack, start, end in clip(named, lo, hi):
+            scopes[stack] = scopes.get(stack, 0.0) + (end - start) * 1e-9
         module_events = [e for e in lines.get(MODULES_LINE, []) if lo <= e[1] < hi]
         busy[dev] = busy_ns(op_events, lo, hi) * 1e-9
         for name, start, end in module_events:
@@ -174,7 +190,7 @@ def summarize_events(
             idle[name] = idle.get(name, 0.0) + secs / len(devices)
     runs = {name: [secs for _, secs in sorted(starts)] for name, starts in modules.items()}
     return Summary(window_s=(hi - lo) * 1e-9, busy_s=busy, modules=runs,
-                   ops=ops, idle_by_host=idle)
+                   ops=ops, idle_by_host=idle, scopes=scopes)
 
 
 def latest_xplane(trace_dir: str) -> str:
@@ -186,20 +202,33 @@ def latest_xplane(trace_dir: str) -> str:
 
 def read_xplane(path: str, device_ids: Sequence[int]
                 ) -> Tuple[Dict[int, Dict[str, List[Event]]], List[Event]]:
-    """Device lines of ``device_ids`` and host span events of one trace."""
+    """Device lines of ``device_ids`` and host span events of one trace; the
+    op events also as ``SCOPES_LINE``, each named by its name stack (``""``
+    where it has none; left out where the stacks do not pair with the
+    events one to one, and ``summarize_events`` then puts all under
+    ``""``)."""
     from jax.profiler import ProfileData
 
+    def wanted(name: str) -> bool:
+        match = DEVICE_PLANE.match(name)
+        return bool(match) and int(match.group(1)) in device_ids
+
     data = ProfileData.from_file(path)
+    stacks = xplane.op_stacks(path, OPS_LINE, NAME_STACK_STAT, wanted)
     devices: Dict[int, Dict[str, List[Event]]] = {}
     host: List[Event] = []
     for plane in data.planes:
-        match = DEVICE_PLANE.match(plane.name)
-        if match and int(match.group(1)) in device_ids:
-            lines = devices.setdefault(int(match.group(1)), {})
+        if wanted(plane.name):
+            lines = devices.setdefault(int(DEVICE_PLANE.match(plane.name).group(1)), {})
+            names = stacks.get(plane.name, [])
             for line in plane.lines:
-                if line.name in (OPS_LINE, MODULES_LINE):
-                    lines[line.name] = [(e.name, e.start_ns, e.end_ns)
-                                        for e in line.events]
+                if line.name not in (OPS_LINE, MODULES_LINE):
+                    continue
+                events = list(line.events)
+                lines[line.name] = [(e.name, e.start_ns, e.end_ns) for e in events]
+                if line.name == OPS_LINE and len(names) == len(events):
+                    lines[SCOPES_LINE] = [(n, e.start_ns, e.end_ns)
+                                          for n, e in zip(names, events)]
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 host += [(e.name, e.start_ns, e.end_ns) for e in line.events
