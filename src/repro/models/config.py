@@ -43,7 +43,12 @@ class ModelConfig:
     moe_experts: int = 0             # 0 → dense
     moe_top_k: int = 2
     moe_every: int = 1               # every Nth ffn is MoE (jamba: 2)
-    moe_capacity_factor: float = 1.25
+    # The chip's share of an expert-parallel layer: the router scores all
+    # ``moe_experts``, this layer holds ``moe_held`` of them (0 → all),
+    # starting at index ``moe_first``, and adds only their part.
+    moe_held: int = 0
+    moe_first: int = 0
+    moe_shared_ff: int = 0           # width of a shared SwiGLU expert (0 → none)
 
     # --- SSM (Mamba-2 / SSD) -------------------------------------------------
     ssm_state: int = 0
@@ -59,13 +64,19 @@ class ModelConfig:
 
     # --- enc-dec ------------------------------------------------------------------
     encoder_layers: int = 0
-    pos_embedding: str = "rope"      # rope | learned
+    pos_embedding: str = "rope"      # rope | learned | none (NoPE)
     max_position: int = 0            # learned-pos table size (0 = seq dependent)
     frontend: str = "none"           # none | audio_stub | vq_stub (see DESIGN.md)
 
     # --- embeddings / output ----------------------------------------------------
     tie_embeddings: bool = False
     logit_softcap: float = 0.0       # grok uses 30.0
+
+    # --- muP constants (granite): 1.0 / 0.0 leave the block as it was ----------
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # on every mixer and FFN output
+    attn_scale: float = 0.0          # 0 → 1/sqrt(head_dim)
+    logits_scaling: float = 1.0      # logits are divided by it
 
     # --- numerics / execution -----------------------------------------------------
     kv_cache_dtype: str = "compute"  # compute | int8 (quantised KV cache)
@@ -88,6 +99,13 @@ class ModelConfig:
             raise ValueError("hybrid family requires attn_every > 0")
         if self.family in ("ssm", "hybrid") and self.ssm_state <= 0:
             raise ValueError(f"{self.family} family requires ssm_state > 0")
+        if self.moe_experts and not (
+            0 <= self.moe_first
+            and self.moe_first + self.experts_held <= self.moe_experts
+        ):
+            raise ValueError("held experts must lie within moe_experts")
+        if self.use_kernels and self.attn_scale:
+            raise ValueError("the flash-attention kernel scales by 1/sqrt(head_dim)")
 
     # --- derived structure --------------------------------------------------------
 
@@ -136,6 +154,11 @@ class ModelConfig:
         return pattern
 
     @property
+    def experts_held(self) -> int:
+        """Routed experts this layer holds (its share of the layer)."""
+        return self.moe_held or self.moe_experts
+
+    @property
     def d_inner(self) -> int:
         """Mamba-2 expanded inner width."""
         return self.ssm_expand * self.d_model
@@ -150,11 +173,11 @@ class ModelConfig:
         return sum(c for _, c in self.param_breakdown())
 
     def active_param_count(self) -> int:
-        """Per-token active parameters (MoE: top-k of experts)."""
+        """Per-token active parameters (MoE: top-k of the held experts)."""
         total = 0
         for name, count in self.param_breakdown():
             if name.endswith(".moe"):
-                total += count * self.moe_top_k // max(1, self.moe_experts)
+                total += count * self.moe_top_k // max(1, self.experts_held)
             else:
                 total += count
         return total
@@ -179,12 +202,12 @@ class ModelConfig:
             return mults * d * f
 
         def moe_ffn() -> int:
-            return self.moe_experts * dense_ffn() + d * self.moe_experts  # + router
+            return self.experts_held * dense_ffn() + d * self.moe_experts  # + router
 
         def mamba_params() -> int:
             di, ns, g = self.d_inner, self.ssm_state, self.ssm_groups
             in_proj = d * (2 * di + 2 * g * ns + self.ssm_nheads)
-            conv = self.ssm_conv * (di + 2 * g * ns)
+            conv = (self.ssm_conv + 1) * (di + 2 * g * ns)  # weights and bias
             out_proj = di * d
             extras = 3 * self.ssm_nheads  # A_log, D, dt_bias
             norm = di
@@ -202,6 +225,9 @@ class ModelConfig:
                     items.append((f"{tagname}.ffn", dense_ffn() + d))
                 elif ffn == "moe":
                     items.append((f"{tagname}.moe", moe_ffn() + d))
+                    if self.moe_shared_ff:
+                        items.append((f"{tagname}.shared_expert",
+                                      3 * d * self.moe_shared_ff))
         if self.family == "encdec":
             # Encoder self-attn + ffn, decoder cross-attn (added to the above
             # decoder stack), learned positions.

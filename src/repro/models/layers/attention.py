@@ -92,15 +92,20 @@ def _sdpa(
     k: jax.Array,
     v: jax.Array,
     mask: Optional[jax.Array],
+    scale: float = 0.0,
 ) -> jax.Array:
     """q: [B,S,H,D]; k,v: [B,KV,D,T] (positions last) — grouped-query
-    dot-product attention. mask broadcasts against [B,KV,G,S,T]."""
+    dot-product attention. mask broadcasts against [B,KV,G,S,T]. Scores are
+    scaled by ``scale``, or by 1/sqrt(D) when it is 0."""
     b, s, h, d = q.shape
     kvh = k.shape[1]
     group = h // kvh
     qg = q.reshape(b, s, kvh, group, d)
     scores = jnp.einsum("bskgd,bkdt->bkgst", qg, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(d))
+    if scale:
+        scores = scores * scale
+    else:
+        scores = scores / jnp.sqrt(jnp.float32(d))
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
@@ -133,7 +138,7 @@ def attend_full(
 
         out = flash_attention(q, k, v, causal=causal)
     else:
-        out = _sdpa(q, positions_last(k), positions_last(v), mask)
+        out = _sdpa(q, positions_last(k), positions_last(v), mask, cfg.attn_scale)
     out = out.reshape(*out.shape[:-2], cfg.n_heads * cfg.head_dim)
     return out @ params["wo"].astype(cdt)
 
@@ -169,7 +174,8 @@ def attend_cached(
     # Mask: only slots <= position are attendable.
     valid = t_idx <= position[:, None]  # [B,T]
     mask = valid[:, None, None, None, :]  # [B,KV,G,1,T]
-    out = _sdpa(q, dequant_kv(cache_k, cdt), dequant_kv(cache_v, cdt), mask)
+    out = _sdpa(q, dequant_kv(cache_k, cdt), dequant_kv(cache_v, cdt), mask,
+                cfg.attn_scale)
     out = out.reshape(*out.shape[:-2], cfg.n_heads * cfg.head_dim)
     return out @ params["wo"].astype(cdt), cache_k, cache_v
 
@@ -183,7 +189,7 @@ def attend_cross(
     """Cross attention (decoder query, encoder memory); no mask, no rope."""
     cdt = _dtype(cfg.compute_dtype)
     q, k, v = _project_qkv(cfg, params, x, kv_input=enc_out, use_rope=False)
-    out = _sdpa(q, positions_last(k), positions_last(v), None)
+    out = _sdpa(q, positions_last(k), positions_last(v), None, cfg.attn_scale)
     out = out.reshape(*out.shape[:-2], cfg.n_heads * cfg.head_dim)
     return out @ params["wo"].astype(cdt)
 

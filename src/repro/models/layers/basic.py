@@ -94,7 +94,10 @@ def init_embedding(cfg, rng: jax.Array) -> Dict:
 
 def embed(cfg, params: Dict, tokens: jax.Array) -> jax.Array:
     out = jnp.take(params["table"], tokens, axis=0)
-    return out.astype(_dtype(cfg.compute_dtype))
+    out = out.astype(_dtype(cfg.compute_dtype))
+    if cfg.embedding_multiplier != 1.0:
+        out = out * cfg.embedding_multiplier
+    return out
 
 
 def unembed(cfg, params: Dict, x: jax.Array) -> jax.Array:
@@ -103,6 +106,8 @@ def unembed(cfg, params: Dict, x: jax.Array) -> jax.Array:
     logits = jnp.einsum(
         "...d,vd->...v", x.astype(jnp.float32), table.astype(jnp.float32)
     )
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.logit_softcap > 0:
         cap = cfg.logit_softcap
         logits = cap * jnp.tanh(logits / cap)
@@ -121,17 +126,19 @@ def _init_linear(rng, d_in: int, d_out: int, dtype) -> jax.Array:
     )
 
 
-def init_ffn(cfg, rng: jax.Array) -> Dict:
+def init_ffn(cfg, rng: jax.Array, d_ff: Optional[int] = None) -> Dict:
+    """A dense FFN of width ``d_ff`` (default ``cfg.d_ff``)."""
     dtype = _dtype(cfg.param_dtype)
+    f = d_ff or cfg.d_ff
     keys = jax.random.split(rng, 3)
     params: Dict = {}
     if cfg.mlp_kind in ("swiglu", "geglu"):
-        params["w_gate"] = _init_linear(keys[0], cfg.d_model, cfg.d_ff, dtype)
-        params["w_up"] = _init_linear(keys[1], cfg.d_model, cfg.d_ff, dtype)
-        params["w_down"] = _init_linear(keys[2], cfg.d_ff, cfg.d_model, dtype)
+        params["w_gate"] = _init_linear(keys[0], cfg.d_model, f, dtype)
+        params["w_up"] = _init_linear(keys[1], cfg.d_model, f, dtype)
+        params["w_down"] = _init_linear(keys[2], f, cfg.d_model, dtype)
     else:  # squared_relu | gelu
-        params["w_up"] = _init_linear(keys[0], cfg.d_model, cfg.d_ff, dtype)
-        params["w_down"] = _init_linear(keys[1], cfg.d_ff, cfg.d_model, dtype)
+        params["w_up"] = _init_linear(keys[0], cfg.d_model, f, dtype)
+        params["w_down"] = _init_linear(keys[1], f, cfg.d_model, dtype)
     return params
 
 

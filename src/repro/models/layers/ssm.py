@@ -240,14 +240,15 @@ def apply_mamba(
     )
     a = -jnp.exp(params["a_log"])
 
-    if cfg.use_kernels:
-        from repro.kernels.ops import ssd_scan
+    with jax.named_scope("ssd"):
+        if cfg.use_kernels:
+            from repro.kernels.ops import ssd_scan
 
-        y, _ = ssd_scan(xs, dt, a, b_mat, c_mat, chunk=cfg.ssm_chunk)
-    else:
-        y, _ = ssd_chunked(
-            xs, dt, a, b_mat, c_mat, cfg.ssm_chunk, initial_state
-        )
+            y, _ = ssd_scan(xs, dt, a, b_mat, c_mat, chunk=cfg.ssm_chunk)
+        else:
+            y, _ = ssd_chunked(
+                xs, dt, a, b_mat, c_mat, cfg.ssm_chunk, initial_state
+            )
     y = y + xs.astype(jnp.float32) * params["d_skip"][None, None, :, None]
     y = y.reshape(bsz, s, di)
 

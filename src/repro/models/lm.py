@@ -94,11 +94,12 @@ def _apply_period(
     for i, (mixer, ffn) in enumerate(cfg.layer_pattern()):
         sub = period_params[f"pos{i}"]
         h = basic.apply_norm(cfg, sub["mixer_norm"], x)
-        if mixer == "attn":
-            h = attend_full(cfg, sub["attn"], h, positions)
-        else:
-            h = apply_mamba(cfg, sub["mamba"], h)
-        x = x + h
+        with jax.named_scope(mixer):
+            if mixer == "attn":
+                h = attend_full(cfg, sub["attn"], h, positions)
+            else:
+                h = apply_mamba(cfg, sub["mamba"], h)
+        x = residual_add(cfg, x, h)
         if ffn != "none":
             h = basic.apply_norm(cfg, sub["ffn_norm"], x)
             if ffn == "moe":
@@ -106,8 +107,15 @@ def _apply_period(
                 aux_total = aux_total + aux
             else:
                 h = basic.apply_ffn(cfg, sub["ffn"], h)
-            x = x + h
+            x = residual_add(cfg, x, h)
     return x, aux_total
+
+
+def residual_add(cfg: ModelConfig, x: jax.Array, h: jax.Array) -> jax.Array:
+    """``x + h``, with ``h`` scaled by the config's residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * cfg.residual_multiplier
+    return x + h
 
 
 def _remat_wrap(cfg: ModelConfig, fn):
@@ -236,24 +244,25 @@ def prefill(
             sub = period_params[f"pos{i}"]
             c = period_cache[f"pos{i}"]
             h = basic.apply_norm(cfg, sub["mixer_norm"], x)
-            if mixer == "attn":
-                _, k, v = _project_qkv(cfg, sub["attn"], h, positions=positions)
-                new_cache[f"pos{i}"] = {
-                    "k": write_kv_prefix(c["k"], k),
-                    "v": write_kv_prefix(c["v"], v),
-                }
-                h = attend_full(cfg, sub["attn"], h, positions)
-            else:
-                h, mamba_state = apply_mamba_with_state(cfg, sub["mamba"], h)
-                new_cache[f"pos{i}"] = mamba_state
-            x = x + h
+            with jax.named_scope(mixer):
+                if mixer == "attn":
+                    _, k, v = _project_qkv(cfg, sub["attn"], h, positions=positions)
+                    new_cache[f"pos{i}"] = {
+                        "k": write_kv_prefix(c["k"], k),
+                        "v": write_kv_prefix(c["v"], v),
+                    }
+                    h = attend_full(cfg, sub["attn"], h, positions)
+                else:
+                    h, mamba_state = apply_mamba_with_state(cfg, sub["mamba"], h)
+                    new_cache[f"pos{i}"] = mamba_state
+            x = residual_add(cfg, x, h)
             if ffn != "none":
                 h = basic.apply_norm(cfg, sub["ffn_norm"], x)
                 if ffn == "moe":
                     h, _ = apply_moe(cfg, sub["moe"], h)
                 else:
                     h = basic.apply_ffn(cfg, sub["ffn"], h)
-                x = x + h
+                x = residual_add(cfg, x, h)
         return x, new_cache
 
     x, new_cache = jax.lax.scan(scan_body, x, (params["blocks"], cache))
@@ -285,22 +294,23 @@ def decode_step(
             sub = period_params[f"pos{i}"]
             c = period_cache[f"pos{i}"]
             h = basic.apply_norm(cfg, sub["mixer_norm"], x)
-            if mixer == "attn":
-                h, ck, cv = attend_cached(
-                    cfg, sub["attn"], h, c["k"], c["v"], position
-                )
-                new_cache[f"pos{i}"] = {"k": ck, "v": cv}
-            else:
-                h, nc = apply_mamba_step(cfg, sub["mamba"], h, c)
-                new_cache[f"pos{i}"] = nc
-            x = x + h
+            with jax.named_scope(mixer):
+                if mixer == "attn":
+                    h, ck, cv = attend_cached(
+                        cfg, sub["attn"], h, c["k"], c["v"], position
+                    )
+                    new_cache[f"pos{i}"] = {"k": ck, "v": cv}
+                else:
+                    h, nc = apply_mamba_step(cfg, sub["mamba"], h, c)
+                    new_cache[f"pos{i}"] = nc
+            x = residual_add(cfg, x, h)
             if ffn != "none":
                 h = basic.apply_norm(cfg, sub["ffn_norm"], x)
                 if ffn == "moe":
                     h, _ = apply_moe(cfg, sub["moe"], h)
                 else:
                     h = basic.apply_ffn(cfg, sub["ffn"], h)
-                x = x + h
+                x = residual_add(cfg, x, h)
         return x, new_cache
 
     x, new_cache = jax.lax.scan(scan_body, x, (params["blocks"], cache))
@@ -331,7 +341,8 @@ def apply_mamba_with_state(cfg, params: Dict, x: jax.Array):
         dt_raw.astype(jnp.float32) + params["dt_bias"][None, None, :]
     )
     a = -jnp.exp(params["a_log"])
-    y, final_state = ssd_chunked(xs, dt, a, b_mat, c_mat, cfg.ssm_chunk)
+    with jax.named_scope("ssd"):
+        y, final_state = ssd_chunked(xs, dt, a, b_mat, c_mat, cfg.ssm_chunk)
     y = y + xs.astype(jnp.float32) * params["d_skip"][None, None, :, None]
     y = y.reshape(bsz, s, di)
 
