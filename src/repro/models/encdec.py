@@ -24,6 +24,7 @@ from repro.models.layers.attention import (
     init_kv_cache,
     kv_cache_shape,
     positions_last,
+    write_kv,
     write_kv_prefix,
 )
 from repro.sharding.ctx import constrain
@@ -230,7 +231,7 @@ def decode_step(
     def body(x, inputs):
         layer, sk, sv, xk, xv = inputs
         h = basic.apply_norm(cfg, layer["self_norm"], x)
-        h, nsk, nsv = attend_cached(cfg, layer["self_attn"], h, sk, sv, position)
+        h, k, v = attend_cached(cfg, layer["self_attn"], h, sk, sv, position)
         x = x + h
         h = basic.apply_norm(cfg, layer["cross_norm"], x)
         # Cross attention against the precomputed encoder K/V.
@@ -240,9 +241,11 @@ def decode_step(
         x = x + o @ layer["cross_attn"]["wo"].astype(cdt)
         h = basic.apply_norm(cfg, layer["ffn_norm"], x)
         x = x + basic.apply_ffn(cfg, layer["ffn"], h)
-        return x, (nsk, nsv)
+        return x, (k, v)
 
-    x, (sk, sv) = jax.lax.scan(
+    # The self K/V are read in the scan; only the token's K/V leave it,
+    # written in place after it. The cross K/V pass through unchanged.
+    x, (k, v) = jax.lax.scan(
         body,
         x,
         (
@@ -255,9 +258,13 @@ def decode_step(
     )
     x = basic.apply_norm(cfg, params["final_norm"], x)
     logits = basic.unembed(cfg, params["embed"], x)
+    own = write_kv(
+        {"self_k": cache["self_k"], "self_v": cache["self_v"]},
+        {"self_k": k, "self_v": v},
+        position,
+    )
     return logits, {
-        "self_k": sk,
-        "self_v": sv,
+        **own,
         "cross_k": cache["cross_k"],
         "cross_v": cache["cross_v"],
     }

@@ -1,9 +1,11 @@
 """Grouped-query attention with RoPE / qk-norm / bias variants + KV cache.
 
 The KV cache keeps positions last: K and V are ``[B, KV, Dh, T]``. That is
-the layout the decode dots read with positions minor, and the one-token
-write is a select on the position axis in that same layout, so a decode
-step reads and writes the cache as stored, with no relayout of it.
+the layout the decode dots read with positions minor. A decode step reads
+the cache once and changes only the new token's position: attention takes
+the token's own K/V beside the cache, and :func:`write_kv` then puts them
+into the (donated) stacked cache, touching only the tiles that hold the
+new positions.
 
 Three entry points:
   * :func:`attend_full`   — full-sequence causal (train / prefill);
@@ -27,8 +29,10 @@ from repro.models.layers.basic import (
     apply_rope,
     rms_norm_headwise,
 )
+from repro.sharding.ctx import map_kv_shards
 
 NEG_INF = -1e30
+LANES = 128  # positions in one tile of the cache's minor (lane) axis
 
 
 def init_attention(cfg, rng: jax.Array, *, cross: bool = False) -> Dict:
@@ -101,16 +105,20 @@ def _sdpa(
     kvh = k.shape[1]
     group = h // kvh
     qg = q.reshape(b, s, kvh, group, d)
-    scores = jnp.einsum("bskgd,bkdt->bkgst", qg, k).astype(jnp.float32)
-    if scale:
-        scores = scores * scale
-    else:
-        scores = scores / jnp.sqrt(jnp.float32(d))
+    scores = _scaled(
+        jnp.einsum("bskgd,bkdt->bkgst", qg, k).astype(jnp.float32), scale, d
+    )
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgst,bkdt->bskgd", probs, v)
     return out.reshape(b, s, h, d)
+
+
+def _scaled(scores: jax.Array, scale: float, d: int) -> jax.Array:
+    if scale:
+        return scores * scale
+    return scores / jnp.sqrt(jnp.float32(d))
 
 
 def positions_last(x: jax.Array) -> jax.Array:
@@ -153,31 +161,59 @@ def attend_cached(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode. x: [B,1,D]; cache_{k,v}: [B,KV,Dh,T]; position: [B].
 
-    Returns (attn output [B,1,D], new cache_k, new cache_v). The new token's
-    K/V are written at ``position``; attention masks out cache slots beyond
-    ``position``.
+    Reads the cache and writes nothing to it. Returns (attn output
+    [B,1,D], the token's K, its V), each of K and V as the cache stores
+    it (:func:`encode_kv`), for :func:`write_kv` to put at ``position``.
+    The token attends to the cached positions before ``position`` and to
+    its own K/V, which is the softmax over positions ``<= position`` that
+    the cache holds once the token is written.
     """
     cdt = _dtype(cfg.compute_dtype)
     q, k_new, v_new = _project_qkv(
         cfg, params, x, positions=position[:, None]
     )
+    k_tok = encode_kv(k_new[:, 0], cache_k)
+    v_tok = encode_kv(v_new[:, 0], cache_v)
     ref = cache_k["q"] if isinstance(cache_k, dict) else cache_k
-    t_idx = jnp.arange(ref.shape[-1])[None, :]  # [1,T]
-
-    # The write is a select on the position axis: it reads and writes the
-    # cache in its stored layout (a scatter there wants head_dim minor and
-    # makes the compiler relayout the layer's whole cache around it).
-    at = t_idx == position[:, None]  # [B,T]
-    cache_k = write_kv(cache_k, k_new[:, 0], at)
-    cache_v = write_kv(cache_v, v_new[:, 0], at)
-
-    # Mask: only slots <= position are attendable.
-    valid = t_idx <= position[:, None]  # [B,T]
-    mask = valid[:, None, None, None, :]  # [B,KV,G,1,T]
-    out = _sdpa(q, dequant_kv(cache_k, cdt), dequant_kv(cache_v, cdt), mask,
-                cfg.attn_scale)
+    earlier = jnp.arange(ref.shape[-1])[None, :] < position[:, None]  # [B,T]
+    out = _sdpa_step(
+        q, dequant_kv(cache_k, cdt), dequant_kv(cache_v, cdt), earlier,
+        dequant_kv(k_tok, cdt), dequant_kv(v_tok, cdt), cfg.attn_scale,
+    )
     out = out.reshape(*out.shape[:-2], cfg.n_heads * cfg.head_dim)
-    return out @ params["wo"].astype(cdt), cache_k, cache_v
+    return out @ params["wo"].astype(cdt), k_tok, v_tok
+
+
+def _sdpa_step(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    earlier: jax.Array,
+    k_own: jax.Array,
+    v_own: jax.Array,
+    scale: float = 0.0,
+) -> jax.Array:
+    """:func:`_sdpa` for one query token. q: [B,1,H,D]; k,v: [B,KV,D,T]
+    cached, of which the positions where ``earlier`` ([B,T]) is set count;
+    k_own, v_own: [B,KV,D], the token's own. The own score is one more
+    column of the float32 softmax: it joins the row's max and sum."""
+    b, _, h, d = q.shape
+    kvh = k.shape[1]
+    qg = q.reshape(b, kvh, h // kvh, d)
+    scores = _scaled(
+        jnp.einsum("bkgd,bkdt->bkgt", qg, k).astype(jnp.float32), scale, d
+    )
+    scores = jnp.where(earlier[:, None, None, :], scores, NEG_INF)
+    own = _scaled(
+        jnp.einsum("bkgd,bkd->bkg", qg, k_own).astype(jnp.float32), scale, d
+    )[..., None]
+    top = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), own)
+    probs = jnp.exp(scores - top)
+    own_prob = jnp.exp(own - top)
+    total = jnp.sum(probs, axis=-1, keepdims=True) + own_prob
+    out = jnp.einsum("bkgt,bkdt->bkgd", (probs / total).astype(v.dtype), v)
+    out = out + (own_prob / total).astype(v.dtype) * v_own[:, :, None, :]
+    return out.reshape(b, 1, h, d)
 
 
 def attend_cross(
@@ -232,18 +268,57 @@ def dequant_kv(c, dtype) -> jax.Array:
     return c.astype(dtype)
 
 
-def write_kv(cache, new: jax.Array, at: jax.Array):
-    """Write one token's K or V (``new`` [B,KV,Dh]) into the positions-last
-    cache where ``at`` ([B,T] bool, one true per row) is set."""
-    at = at[:, None, None, :]
-    new = new[..., None]  # [B,KV,Dh,1]
+def encode_kv(new: jax.Array, cache):
+    """One token's K or V (``new`` [B,KV,Dh]) as ``cache`` stores it: in
+    the cache's dtype, or int8 ``{"q": [B,KV,Dh], "scale": [B,KV,1]}``."""
     if isinstance(cache, dict):
-        enc = quant_kv(new)
-        return {
-            "q": jnp.where(at, enc["q"], cache["q"]),
-            "scale": jnp.where(at, enc["scale"], cache["scale"]),
-        }
-    return jnp.where(at, new.astype(cache.dtype), cache)
+        return jax.tree.map(lambda a: a[..., 0], quant_kv(new[..., None]))
+    return new.astype(cache.dtype)
+
+
+def write_kv(cache, new, position: jax.Array):
+    """Write each layer's token K/V into the stacked positions-last cache,
+    in place under a jit that donates the cache.
+
+    ``cache`` is a tree of ``[L,B,KV,Dh,T]`` leaves (K and V, or their
+    int8 ``q`` and ``scale``) and ``new`` the same tree of ``[L,B,KV,Dh]``
+    token values (:func:`encode_kv`); slot ``b`` is written at
+    ``position[b]``. One loop over the slots reads and writes, in every
+    leaf, only the tile of ``LANES`` positions that holds the slot's
+    position. A scatter of the token's column instead makes the compiler
+    relayout the whole cache around it. Under a mesh each device writes
+    its own shard (:func:`map_kv_shards`).
+    """
+    return map_kv_shards(_write_tokens, cache, new, position)
+
+
+def _write_tokens(cache, new, position: jax.Array):
+    leaves, tree = jax.tree.flatten(cache)
+    tokens = jax.tree.leaves(new)
+
+    def slot(b, leaves):
+        return [_write_tile(c, t, b, position[b]) for c, t in zip(leaves, tokens)]
+
+    return jax.tree.unflatten(
+        tree, jax.lax.fori_loop(0, leaves[0].shape[1], slot, leaves)
+    )
+
+
+def _write_tile(cache: jax.Array, new: jax.Array, b, pos) -> jax.Array:
+    """``cache`` with slot ``b``'s column ``new[:, b]`` selected in at
+    ``pos`` within the tile that holds it. A ``pos`` outside the position
+    axis matches no column, and the tile is written back as it was."""
+    n_layers, _, kvh, dh, t = cache.shape
+    tile = min(LANES, t)
+    start = jnp.clip(pos // tile * tile, 0, t - tile)
+    old = jax.lax.dynamic_slice(
+        cache, (0, b, 0, 0, start), (n_layers, 1, kvh, dh, tile)
+    )
+    col = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1)[..., None]
+    at = jnp.arange(tile) == pos - start
+    return jax.lax.dynamic_update_slice(
+        cache, jnp.where(at, col.astype(cache.dtype), old), (0, b, 0, 0, start)
+    )
 
 
 def write_kv_prefix(cache, new: jax.Array):

@@ -24,6 +24,7 @@ from repro.models.layers.attention import (
     attend_full,
     init_attention,
     init_kv_cache,
+    write_kv,
     write_kv_prefix,
 )
 from repro.models.layers.moe import apply_moe, init_moe
@@ -281,28 +282,31 @@ def decode_step(
 ) -> Tuple[jax.Array, Dict]:
     """One incremental decode step. Returns (logits [B,1,V], new cache).
 
-    Each period's cache comes in as the scan's ``xs`` and leaves as its
-    ``ys``: the token write is a select in the stored layout, written
-    straight into the new stacked cache, so the step copies no cache.
+    Each period's cache comes in as the scan's ``xs``. The attention layers
+    read their K/V there and emit only the token's K/V as ``ys``; one write
+    after the scan puts those at ``position`` in place, so under a jit that
+    donates the cache the step moves only the tiles it changes. Mamba
+    state, which changes whole every step, leaves as the scan's ``ys``.
     """
     x = basic.embed(cfg, params["embed"], token[:, None])
+    pattern = cfg.layer_pattern()
 
     def scan_body(x, inputs):
         period_params, period_cache = inputs
-        new_cache: Dict = {}
-        for i, (mixer, ffn) in enumerate(cfg.layer_pattern()):
+        out: Dict = {}
+        for i, (mixer, ffn) in enumerate(pattern):
             sub = period_params[f"pos{i}"]
             c = period_cache[f"pos{i}"]
             h = basic.apply_norm(cfg, sub["mixer_norm"], x)
             with jax.named_scope(mixer):
                 if mixer == "attn":
-                    h, ck, cv = attend_cached(
+                    h, k, v = attend_cached(
                         cfg, sub["attn"], h, c["k"], c["v"], position
                     )
-                    new_cache[f"pos{i}"] = {"k": ck, "v": cv}
+                    out[f"pos{i}"] = {"k": k, "v": v}
                 else:
                     h, nc = apply_mamba_step(cfg, sub["mamba"], h, c)
-                    new_cache[f"pos{i}"] = nc
+                    out[f"pos{i}"] = nc
             x = residual_add(cfg, x, h)
             if ffn != "none":
                 h = basic.apply_norm(cfg, sub["ffn_norm"], x)
@@ -311,9 +315,14 @@ def decode_step(
                 else:
                     h = basic.apply_ffn(cfg, sub["ffn"], h)
                 x = residual_add(cfg, x, h)
-        return x, new_cache
+        return x, out
 
-    x, new_cache = jax.lax.scan(scan_body, x, (params["blocks"], cache))
+    x, out = jax.lax.scan(scan_body, x, (params["blocks"], cache))
+    new_cache = dict(out)
+    attn = [f"pos{i}" for i, (mixer, _) in enumerate(pattern) if mixer == "attn"]
+    if attn:
+        new_cache.update(write_kv({key: cache[key] for key in attn},
+                                  {key: out[key] for key in attn}, position))
     x = basic.apply_norm(cfg, params["final_norm"], x)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = basic.unembed(cfg, head, x)

@@ -80,6 +80,14 @@ class _SlotState:
     placement: object                   # the platform Placement ticket
 
 
+def jit_decode(model: Model):
+    """The replica's decode program. The slot cache (argument 1) is
+    donated: the step's cache is the buffer it was given, with the new
+    token's K/V written into it in place, and the caller's old reference
+    to it is dead."""
+    return jax.jit(model.decode, donate_argnums=(1,))
+
+
 class Replica:
     """One model replica with slot-batched caches.
 
@@ -113,7 +121,7 @@ class Replica:
         )
         self.active: Dict[int, _SlotState] = {}   # slot index -> state
         self.alive = True
-        self._decode = jax.jit(self.model.decode)
+        self._decode = jit_decode(self.model)
 
         def prefill_b1(params, batch):
             cache = self.model.init_cache(1, max_len, enc_len=max_len)

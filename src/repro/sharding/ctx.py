@@ -50,16 +50,30 @@ def _resolve(entry, dp_axes, tp_axis, vocab_axis):
     return entry
 
 
+def _size(mesh, axes) -> int:
+    names = (axes,) if isinstance(axes, str) else tuple(axes or ())
+    size = 1
+    for a in names:
+        size *= mesh.shape[a]
+    return size
+
+
 def current_dp_size() -> int:
     """Product of the data-parallel axis sizes (1 outside a context)."""
     ctx = getattr(_STATE, "ctx", None)
-    if ctx is None:
-        return 1
-    mesh, dp_axes = ctx[0], ctx[1]
-    size = 1
-    for a in dp_axes:
-        size *= mesh.shape[a]
-    return size
+    return 1 if ctx is None else _size(ctx[0], ctx[1])
+
+
+def _spec(ctx, shape, spec_kinds) -> P:
+    """``spec_kinds`` resolved against the context, each entry dropped
+    where it does not evenly divide its dim."""
+    mesh, dp_axes, tp_axis, vocab_axis = ctx
+    entries = []
+    for dim, kind in zip(shape, spec_kinds):
+        axes = _resolve(kind, dp_axes, tp_axis, vocab_axis)
+        entries.append(axes if axes is not None and dim % _size(mesh, axes) == 0
+                       else None)
+    return P(*entries)
 
 
 def constrain(x: jax.Array, spec_kinds: Sequence) -> jax.Array:
@@ -71,18 +85,40 @@ def constrain(x: jax.Array, spec_kinds: Sequence) -> jax.Array:
     ctx = getattr(_STATE, "ctx", None)
     if ctx is None:
         return x
-    mesh, dp_axes, tp_axis, vocab_axis = ctx
-    entries = []
-    for dim, kind in zip(x.shape, spec_kinds):
-        axes = _resolve(kind, dp_axes, tp_axis, vocab_axis)
-        if axes is None:
-            entries.append(None)
-            continue
-        names = (axes,) if isinstance(axes, str) else tuple(axes)
-        size = 1
-        for a in names:
-            size *= mesh.shape[a]
-        entries.append(axes if dim % size == 0 else None)
     return jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(mesh, P(*entries))
+        x, jax.sharding.NamedSharding(ctx[0], _spec(ctx, x.shape, spec_kinds))
     )
+
+
+def map_kv_shards(fn, cache, new, position: jax.Array):
+    """``fn(cache, new, position)`` on each device's shard of a
+    positions-last K/V cache, or on the whole cache outside a context.
+
+    ``cache`` leaves are ``[L, B, KV, Dh, T]`` with B over the data axes
+    and T over the model axis, as ``specs.cache_shardings`` places them;
+    ``new`` leaves are ``[L, B, KV, Dh]`` and ``position`` is ``[B]``. Each
+    shard sees ``position`` relative to its own first position, so a
+    position held by another device falls outside its position axis.
+    """
+    ctx = getattr(_STATE, "ctx", None)
+    if ctx is None:
+        return fn(cache, new, position)
+    mesh = ctx[0]
+    shape = jax.tree.leaves(cache)[0].shape
+    # T goes over the model axis, which the context keeps as its vocab axis
+    # also where the layers see no model axis (``tp_scope="vocab"``).
+    cache_spec = _spec(ctx, shape, (None, "dp", None, None, "vocab"))
+    slots, positions = cache_spec[1], cache_spec[4]
+
+    def local(cache, new, position):
+        if positions is not None:
+            first = jax.lax.axis_index(positions) * (
+                shape[-1] // _size(mesh, positions)
+            )
+            position = position - first
+        return fn(cache, new, position)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(cache_spec, P(None, slots), P(slots)),
+        out_specs=cache_spec,
+    )(cache, new, position)

@@ -218,33 +218,49 @@ class TestSSD:
 
 
 # One family each: plain GQA decoder, attention + mamba + MoE hybrid,
-# encoder-decoder, and the int8-quantised KV cache.
+# encoder-decoder (its learned position table widened from the smoke
+# config's 128 to the test's 256 positions), and the int8-quantised KV cache.
 _MIXED_POSITION_CASES = {
     "lm": ("smollm_135m", {}),
     "hybrid": ("jamba_1_5_large_398b", {}),
-    "encdec": ("whisper_small", {}),
+    "encdec": ("whisper_small", {"max_position": 256}),
     "int8_kv": ("smollm_135m", {"kv_cache_dtype": "int8"}),
 }
 
 
+def _self_kv(cache):
+    """The self-attention K/V of a cache, by name: each ``[L,B,KV,Dh,T]``,
+    or an int8 ``{"q", "scale"}`` pair of such leaves."""
+    if "self_k" in cache:
+        return {name: cache[name] for name in ("self_k", "self_v")}
+    return {f"{pos}.{kv}": c[kv] for pos, c in cache.items() if "k" in c
+            for kv in ("k", "v")}
+
+
 @pytest.mark.parametrize("case", sorted(_MIXED_POSITION_CASES))
 def test_decode_slots_at_mixed_positions_match_forward(case):
-    """One batched decode step over slots at positions 0, mid and
-    ``max_len - 2``, each filled the way a replica admits a request
-    (batch-1 prefill merged into its slot), gives each slot the logits of
-    a full forward pass over that slot's tokens."""
+    """One batched decode step over slots at positions 0, either side of a
+    128-position tile boundary and ``max_len - 2``, each filled the way a
+    replica admits a request (batch-1 prefill merged into its slot), gives
+    each slot the logits of a full forward pass over that slot's tokens. It
+    writes each slot's new K/V, as a prefill through the new token projects
+    them, at the slot's position and leaves every other cache position
+    bit-equal."""
+    from repro.models.layers.attention import dequant_kv
+
     arch, extra = _MIXED_POSITION_CASES[case]
     cfg = dataclasses.replace(smoke_config(arch), compute_dtype="float32", **extra)
     model = Model(cfg)
     params = model.init_params(RNG)
-    max_len, enc_len = 16, 8
-    positions = [0, max_len // 2 - 1, max_len - 2]
+    max_len, enc_len = 256, 8
+    positions = [0, max_len // 2 - 1, max_len // 2, max_len - 2]
     keys = jax.random.split(jax.random.PRNGKey(1), 2 * len(positions))
     cache = model.init_cache(len(positions), max_len, enc_len=enc_len)
-    expected, last = [], []
+    expected, last, written = [], [], []
     for slot, pos in enumerate(positions):
         toks = jax.random.randint(keys[2 * slot], (1, pos + 1), 0, cfg.vocab_size)
         prompt = {"tokens": toks[:, :pos]}
+        through = {"tokens": toks}
         if cfg.family == "encdec":
             frames = jax.random.normal(
                 keys[2 * slot + 1], (1, enc_len, cfg.d_model), jnp.float32
@@ -254,6 +270,7 @@ def test_decode_slots_at_mixed_positions_match_forward(case):
             # The cross K/V come with a prompt; at position 0 its one token
             # is written again by the decode step.
             prompt = {"frames": frames, "tokens": toks[:, : max(pos, 1)]}
+            through["frames"] = frames
         else:
             expected.append(lm_mod.forward(cfg, params, toks)[0][0, -1])
         if prompt["tokens"].shape[1]:
@@ -262,6 +279,10 @@ def test_decode_slots_at_mixed_positions_match_forward(case):
             cache = jax.tree.map(
                 lambda big, o, s=slot: big.at[:, s].set(o[:, 0]), cache, one
             )
+        _, one = model.prefill(
+            params, through, model.init_cache(1, max_len, enc_len=enc_len)
+        )
+        written.append(_self_kv(one))
         last.append(toks[0, pos])
     step, new_cache = model.decode(
         params, cache, jnp.stack(last), jnp.asarray(positions, jnp.int32)
@@ -273,3 +294,62 @@ def test_decode_slots_at_mixed_positions_match_forward(case):
         err = float(jnp.max(jnp.abs(step[slot, 0] - want)))
         scale = float(jnp.max(jnp.abs(want))) + 1e-9
         assert err / scale < tol, (case, positions[slot], err, scale)
+
+    new_pos = (jnp.arange(max_len)[None, :]
+               == jnp.asarray(positions)[:, None])[None, :, None, None, :]
+    before, after = _self_kv(cache), _self_kv(new_cache)
+    for name in before:
+        kept = jax.tree.map(
+            lambda a, b: bool(jnp.array_equal(jnp.where(new_pos, 0, a),
+                                              jnp.where(new_pos, 0, b))),
+            before[name], after[name],
+        )
+        assert all(jax.tree.leaves(kept)), (case, name)
+        got = dequant_kv(after[name], jnp.float32)
+        for slot, pos in enumerate(positions):
+            want = dequant_kv(written[slot][name], jnp.float32)[:, 0, ..., pos]
+            err = float(jnp.max(jnp.abs(got[:, slot, ..., pos] - want)))
+            scale = float(jnp.max(jnp.abs(want))) + 1e-9
+            assert err / scale < tol, (case, name, pos, err, scale)
+    if cfg.family == "encdec":
+        for name in ("cross_k", "cross_v"):
+            assert bool(jnp.array_equal(cache[name], new_cache[name]))
+
+
+class TestKvWrite:
+    @pytest.mark.parametrize(
+        "dtype,n_layers,slots,kvh,dh,t,pos",
+        [
+            # 128-position tiles, slots on either side of a tile boundary.
+            (jnp.bfloat16, 2, 4, 2, 16, 256, (0, 127, 128, 255)),
+            (jnp.int8, 3, 3, 2, 32, 384, (129, 0, 383)),
+            # The int8 cache's scale leaf: head_dim 1.
+            (jnp.float32, 2, 3, 4, 1, 128, (5, 127, 64)),
+            # A position axis shorter than a tile is one tile.
+            (jnp.float32, 1, 2, 3, 8, 48, (47, 1)),
+            # One that is no multiple of 128: its last tile overlaps the one
+            # before it.
+            (jnp.bfloat16, 3, 3, 8, 128, 200, (199, 71, 128)),
+        ],
+    )
+    def test_matches_scatter(self, dtype, n_layers, slots, kvh, dh, t, pos):
+        """``write_kv`` puts slot b's token K/V at ``pos[b]`` of every leaf
+        it is given, as a plain scatter does, into the cache a donating jit
+        hands it."""
+        from repro.models.layers.attention import write_kv
+
+        ks = jax.random.split(RNG, 4)
+        shape = (n_layers, slots, kvh, dh, t)
+        cache = {kv: (jax.random.normal(k, shape) * 50).astype(dtype)
+                 for kv, k in zip("kv", ks[:2])}
+        new = {kv: (jax.random.normal(k, shape[:-1]) * 50).astype(dtype)
+               for kv, k in zip("kv", ks[2:])}
+        pos = jnp.asarray(pos, jnp.int32)
+        slot = jnp.arange(slots)
+        expect = {kv: cache[kv].at[:, slot, :, :, pos].set(
+            new[kv].transpose(1, 0, 2, 3)) for kv in cache}
+        given = jax.tree.map(jnp.copy, cache)
+        out = jax.jit(write_kv, donate_argnums=(0,))(given, new, pos)
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(given))
+        for kv in cache:
+            assert bool(jnp.array_equal(out[kv], expect[kv])), kv

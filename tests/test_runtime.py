@@ -223,6 +223,52 @@ class TestServingEngine:
         assert {r.replica for r in reqs} == {"r1", "r2"}
 
 
+class TestDonatedDecode:
+    def test_served_tokens_match_requests_served_alone(self):
+        """Decode donates the slot cache and writes only the new token's
+        K/V into it. Requests served together, through admission, decode
+        steps, admissions merged into a cache that decode steps have since
+        written, and a replica loss that requeues its requests, get the
+        tokens each gets served alone; every step's old cache is dead
+        after it, and none is read again."""
+        prompts = [[1, 2, 3], [4, 5, 6, 7, 8], [9, 10], [11, 12, 13, 14],
+                   [15, 16, 17]]
+        alone = ServingEngine(tapp_script=None)
+        alone.add_controller("C", zone="z")
+        alone.add_replica(_small_replica("r", "z", seed=1))
+        want = []
+        for prompt in prompts:
+            req = alone.submit("smollm-135m", prompt, max_new_tokens=6)
+            alone.run_until_done(max_ticks=50)
+            want.append(req.output)
+
+        engine = ServingEngine(tapp_script=ZONED_SCRIPT)
+        engine.add_controller("EdgeCtl", zone="edge")
+        engine.add_controller("CloudCtl", zone="cloud")
+        replicas = [_small_replica("r-edge", "edge", ["edge"], seed=1),
+                    _small_replica("r-cloud", "cloud", ["cloud"], seed=1)]
+        for replica in replicas:
+            engine.add_replica(replica)
+        reqs = [engine.submit("smollm-135m", p, max_new_tokens=6)
+                for p in prompts[:3]]
+        engine.step_once()
+        held = [leaf for r in replicas if r.active
+                for leaf in jax.tree.leaves(r.cache)]
+        engine.step_once()
+        assert held and all(leaf.is_deleted() for leaf in held)
+        # Two more arrive after decode steps: one is merged into a slot of
+        # a cache the steps have written, one waits for a slot.
+        reqs += [engine.submit("smollm-135m", p, max_new_tokens=6)
+                 for p in prompts[3:]]
+        engine.step_once()
+        assert any(r.replica == "r-edge" and r.state == "running"
+                   for r in reqs)
+        engine.remove_replica("r-edge")
+        engine.run_until_done(max_ticks=200)
+        assert all(r.state == "done" for r in reqs)
+        assert [r.output for r in reqs] == want
+
+
 class TestRequestTimes:
     def test_each_time_is_set_when_its_state_changes(self):
         import time as _time
